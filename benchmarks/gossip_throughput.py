@@ -33,9 +33,9 @@ from repro.models.transformer import init_lm, abstract_lm
 from repro.optim import adamw
 from repro.sharding.logical import DEFAULT_RULES, Lx, tree_specs
 from repro.train.trainer import make_allreduce_step, make_gossip_step, train_shardings
-from repro.launch.mesh import compat_make_mesh, use_mesh
 
-mesh = compat_make_mesh((8, 1), ("data", "model"))
+mesh = jax.make_mesh((8, 1), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = ArchConfig(name="bench-tiny", n_layers=2, d_model=128, n_heads=4,
                  n_kv_heads=2, d_ff=256, vocab_size=512, vocab_pad_multiple=128,
                  dtype="float32", pattern=(LayerSpec(),), remat=False)
@@ -44,7 +44,7 @@ opt = adamw(3e-3)
 key = jax.random.PRNGKey(0)
 out = {}
 
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     # ---- all-reduce baseline ----
     params, _ = init_lm(cfg, key)
     state = opt.init(params)

@@ -53,6 +53,9 @@ def main(argv=None) -> int:
                     help="reduced sweeps (CI-sized)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     names = list(BENCHES) if not args.only else args.only.split(",")
     failures = 0
     print("name,us_per_call,derived")

@@ -167,13 +167,15 @@ def dispatch_rows(quick: bool = False,
     multi-process queue (``workers=``) vs the in-process path.
 
     Workers are fresh processes, so without care the measurement is all
-    XLA compilation: every run shares one persistent compile-cache
-    directory and a warm-up dispatch populates it first — after that a
-    worker loads the compiled chunk program from the cache in well under
-    a second, and the row measures queue + process overhead, which is
-    the number the acceptance target bounds (workers=1 within 10% of
-    in-process; workers=2 faster — *when the host has 2+ cores*; the
-    rows record ``n_cores`` so the CI gate can tell).
+    XLA compilation: every run shares the persistent compile cache
+    (``repro.launch.cache``) and a warm-up dispatch populates it first —
+    after that a worker loads the compiled chunk program from the cache in
+    well under a second, and the row measures queue + process overhead,
+    which is the number the acceptance target bounds (workers=1 within 10%
+    of in-process; workers=2 faster — *when the host has 2+ cores*; the
+    rows record ``n_cores`` so the CI gate can tell). The dispatched runs
+    come first: their workers need the device, which this process holds
+    once its own in-process sweep has run.
 
     The workload is deliberately bigger than the throughput bench's: a
     dispatched study pays a fixed per-run cost (worker spawn + jax
@@ -194,6 +196,24 @@ def dispatch_rows(quick: bool = False,
     n_runs = len(ps) * len(seeds)
     total_slots = n_runs * cfg.n_slots
 
+    dispatched = {}
+    work_root = tempfile.mkdtemp(prefix="fg-bench-dispatch-")
+    try:
+        for workers in (1, 2):
+            best = float("inf")
+            for rep in range(reps + 1):  # rep 0 warms the compile cache
+                qd = os.path.join(work_root, f"q{workers}_{rep}")
+                t0 = time.time()
+                sweep.run(ps, cfg, seeds, **kw, workers=workers,
+                          queue_dir=qd)
+                wall = time.time() - t0
+                if rep > 0:
+                    best = min(best, wall)
+                shutil.rmtree(qd, ignore_errors=True)
+            dispatched[workers] = best
+    finally:
+        shutil.rmtree(work_root, ignore_errors=True)
+
     sweep.run(ps, cfg, seeds, **kw)  # compile the in-process program
     inproc_s = float("inf")
     for _ in range(reps):
@@ -201,33 +221,19 @@ def dispatch_rows(quick: bool = False,
         sweep.run(ps, cfg, seeds, **kw)
         inproc_s = min(inproc_s, time.time() - t0)
 
+    n_devices = len(jax.devices())
     rows = [dict(mode="dispatch_inproc", workers=0, wall_s=round(inproc_s, 3),
                  slots_runs_per_s=round(total_slots / inproc_s),
                  overhead_pct=0.0, n_cores=os.cpu_count(),
-                 n_devices=len(jax.devices()))]
-    work_root = tempfile.mkdtemp(prefix="fg-bench-dispatch-")
-    try:
-        cache = os.path.join(work_root, "xla_cache")
-        for workers in (1, 2):
-            best = float("inf")
-            for rep in range(reps + 1):  # rep 0 warms the compile cache
-                qd = os.path.join(work_root, f"q{workers}_{rep}")
-                t0 = time.time()
-                sweep.run(ps, cfg, seeds, **kw, workers=workers,
-                          queue_dir=qd, xla_cache_dir=cache)
-                wall = time.time() - t0
-                if rep > 0:
-                    best = min(best, wall)
-                shutil.rmtree(qd, ignore_errors=True)
-            rows.append(dict(
-                mode=f"dispatch_workers_{workers}", workers=workers,
-                wall_s=round(best, 3),
-                slots_runs_per_s=round(total_slots / best),
-                overhead_pct=round(100.0 * (best / inproc_s - 1.0), 1),
-                n_cores=os.cpu_count(), n_devices=len(jax.devices()),
-            ))
-    finally:
-        shutil.rmtree(work_root, ignore_errors=True)
+                 n_devices=n_devices)]
+    for workers, best in dispatched.items():
+        rows.append(dict(
+            mode=f"dispatch_workers_{workers}", workers=workers,
+            wall_s=round(best, 3),
+            slots_runs_per_s=round(total_slots / best),
+            overhead_pct=round(100.0 * (best / inproc_s - 1.0), 1),
+            n_cores=os.cpu_count(), n_devices=n_devices,
+        ))
     return rows
 
 
@@ -392,6 +398,9 @@ if __name__ == "__main__":
                          "against the in-process reduced sweep instead of "
                          "running the sweep benchmark")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.scaling:
         main_scaling([int(x) for x in args.scaling.split(",")])
     elif args.dispatch:

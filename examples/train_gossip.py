@@ -28,7 +28,6 @@ from repro.core.meanfield import solve_fixed_point
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.models.transformer import init_lm
 from repro.optim import adamw, cosine_schedule
-from repro.launch.mesh import compat_make_mesh, use_mesh
 from repro.train.trainer import (
     make_allreduce_step, make_gossip_step, train_shardings,
 )
@@ -44,7 +43,8 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/fg_ckpt")
     args = ap.parse_args()
 
-    mesh = compat_make_mesh((8, 1), ("data", "model"))
+    mesh = jax.make_mesh((8, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = ArchConfig(
         name="fg-lm", n_layers=args.layers, d_model=args.d_model, n_heads=4,
         n_kv_heads=2, d_ff=4 * args.d_model, vocab_size=2048,
@@ -66,7 +66,7 @@ def main():
     print(f"mean-field gates: success={gcfg.success_prob:.3f} "
           f"busy={gcfg.busy_prob:.4f} churn={gcfg.churn_prob:.5f}")
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         # ---------------- all-reduce baseline ----------------
         params, _ = init_lm(cfg, key)
         state = opt.init(params)

@@ -45,14 +45,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.merge import MergePolicy, merge_weights
 from repro.kernels.gossip_merge import gossip_merge
 
-try:  # jax >= 0.8 (kwarg renamed check_rep -> check_vma)
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 __all__ = [
     "GossipConfig",
@@ -241,12 +233,12 @@ def build_gossip_round(
 
         return new_params, new_count, new_age
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, scalar_spec, scalar_spec, param_specs, P()),
         out_specs=(param_specs, scalar_spec, scalar_spec),
-        check_rep=False,
+        check_vma=False,
     )
 
     def round_fn(params, state: dict, default_params, round_idx):

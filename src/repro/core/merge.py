@@ -151,10 +151,12 @@ def merge_weights(
         w_own = jnp.asarray(0.5)
     elif policy == "obs_count":
         # two untrained replicas (both counts zero) merge symmetrically:
-        # without the fallback w_own = 0/1 = 0 hands the peer full weight
+        # without the fallback w_own = 0/0 would be NaN. The safe divisor
+        # only fills the untaken branch, so fractional (clipped) counts
+        # keep their exact ratio.
         tot = own_count + peer_count
         w_own = jnp.where(
-            tot > 0.0, own_count / jnp.maximum(tot, 1.0), 0.5
+            tot > 0.0, own_count / jnp.where(tot > 0.0, tot, 1.0), 0.5
         )
     elif policy == "staleness":
         # shift by the min age: w_own only depends on the age *gap*, and

@@ -26,9 +26,13 @@ each path derives from its own d² is self-consistent, and the discrete
 outputs are bitwise stable (a flip would need two candidate distances
 within one ulp of each other).
 
-Grid: (n_i,) over row tiles; each step reads the full coordinate row
-(N ≤ a few thousand keeps the (blk_i, N) tile comfortably inside VMEM:
-128 x 4096 f32 = 2 MB).
+Grid: (n_i,) over row tiles. Each step reads the whole column set in
+*bit-plane* order — a ``(32, N/32)`` array per quantity whose row ``b``
+holds columns ``b, 32 + b, 64 + b, ...`` — so the close bits of plane
+``b`` OR straight into bit ``b`` of every output word: no unsigned
+reduction and no reshape of the lane axis, neither of which the TPU
+lowering accepts. N ≤ a few thousand keeps a (blk_i, N/32) word tile and
+its 32 planes small in VMEM.
 
 Dispatch rule (``repro.sim.contacts.pairwise_close`` /
 ``match_candidates``): the compiled kernel runs only on TPU backends;
@@ -47,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "pairwise_contacts",
@@ -254,43 +259,73 @@ def pairwise_contacts_ref(pos, in_rz, elig, prevw, r_tx2, access=None):
     return closew, best_j, has
 
 
-def _kernel(xi_ref, yi_ref, x_ref, y_ref, zwi_ref, zw_ref, eligi_ref,
-            elig_ref, prevw_ref, closew_ref, bestj_ref, has_ref, *,
-            r_tx2, blk_i, n_pad):
-    # the pack/unpack helpers are plain jnp ops, valid inside the kernel
-    # at these 32-aligned tile shapes — one word-layout implementation
-    from repro.sim.compute import pack_mask, unpack_mask
+def _or_bit(words, bit, b: int):
+    """``words | (bit << b)`` in int32 — the in-kernel half of the
+    :func:`repro.sim.compute.pack_mask` layout. Mosaic has no unsigned
+    reductions and cannot split the lane axis into (word, bit), so the
+    kernels build each word from 32 bit planes with shifts and ORs (bit 31
+    lands in the sign bit) and the wrapper bitcasts the result to uint32."""
+    return words | (bit.astype(jnp.int32) << b)
 
+
+def _kernel(xi_ref, yi_ref, zwi_ref, eligi_ref, xb_ref, yb_ref, zwb_ref,
+            eligb_ref, prevw_ref, closew_ref, bestj_ref, has_ref, *,
+            r_tx2, blk_i):
+    # Row inputs are (blk_i, 1) columns. Column inputs are bit-plane major,
+    # (32, nw): entry [b, w] is node 32 * w + b, so plane b is one sublane
+    # row and its close bits OR straight into bit b of every word.
     ti = pl.program_id(0)
+    xi, yi = xi_ref[...], yi_ref[...]
+    zwi = zwi_ref[...]
+    eligi = eligi_ref[...] != 0
+    prev = prevw_ref[...]                             # (blk_i, nw) int32
+    shape = prev.shape
+    row = ti * blk_i + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col0 = 32 * jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
-    xi = xi_ref[0]                                    # (blk_i,)
-    yi = yi_ref[0]
-    dx = xi[:, None] - x_ref[0][None, :]              # (blk_i, n_pad)
-    dy = yi[:, None] - y_ref[0][None, :]
-    d2 = dx * dx + dy * dy
+    closew = jnp.zeros(shape, jnp.int32)
+    best = jnp.full(shape, jnp.inf, jnp.float32)      # per-word running min
+    best_b = jnp.zeros(shape, jnp.int32)              # ... and its first bit
+    for b in range(32):
+        dx = xi - xb_ref[b:b + 1, :]                  # (blk_i, nw)
+        dy = yi - yb_ref[b:b + 1, :]
+        d2 = dx * dx + dy * dy
+        # zone-sharing gate on the membership words — for a single zone the
+        # words are 0/1 and this is bitwise the old in_rz_i & in_rz_j
+        close = (
+            (d2 <= r_tx2)
+            & ((zwi & zwb_ref[b:b + 1, :]) != 0)
+            & (row != col0 + b)
+        )
+        closew = _or_bit(closew, close, b)
+        was = (jax.lax.shift_right_logical(prev, b) & 1) != 0
+        cand = close & ~was & eligi & (eligb_ref[b:b + 1, :] != 0)
+        score = jnp.where(cand, d2, jnp.inf)
+        better = score < best                         # strict: first bit wins
+        best = jnp.where(better, score, best)
+        best_b = jnp.where(better, b, best_b)
+    closew_ref[...] = closew
 
-    row = ti * blk_i + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
-    # zone-sharing gate on the uint32 membership words — for a single
-    # zone the words are 0/1 and this is bitwise the old in_rz_i & in_rz_j
-    close = (
-        (d2 <= r_tx2)
-        & ((zwi_ref[0][:, None] & zw_ref[0][None, :]) != 0)
-        & (row != col)
+    # row argmin with the oracle's first-minimum tie-break: the lowest
+    # column attaining the row min lives in the lowest word attaining it,
+    # at that word's first attaining bit
+    bmin = jnp.min(best, axis=1, keepdims=True)       # (blk_i, 1)
+    has = bmin < jnp.inf
+    j = jnp.min(
+        jnp.where(best == bmin, col0 + best_b, jnp.iinfo(jnp.int32).max),
+        axis=1, keepdims=True,
     )
+    bestj_ref[...] = jnp.where(has, j, -1)
+    has_ref[...] = has.astype(jnp.int32)
 
-    closew_ref[...] = pack_mask(close)
-    prev = unpack_mask(prevw_ref[...], n_pad)
-    cand = (
-        close & ~prev
-        & (eligi_ref[0] != 0)[:, None] & (elig_ref[0] != 0)[None, :]
-    )
-    scores = jnp.where(cand, d2, jnp.inf)
-    has = jnp.isfinite(jnp.min(scores, axis=1))
-    bestj_ref[0] = jnp.where(
-        has, jnp.argmin(scores, axis=1).astype(jnp.int32), -1
-    )
-    has_ref[0] = has.astype(jnp.int32)
+
+def _bit_planes(v: jnp.ndarray) -> jnp.ndarray:
+    """(n_pad,) -> (32, n_pad // 32) with [b, w] = v[32 * w + b]."""
+    return v.reshape(-1, 32).T
+
+
+def _as_i32(words: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
 @functools.partial(
@@ -320,44 +355,33 @@ def pairwise_contacts(pos, in_rz, elig, prevw, r_tx2, access=None, *,
     # word is zeroed before the kernel, so the in-kernel intersection gate
     # needs no change and kernel/oracle stay bitwise comparable
     zw = apply_access(zw, access)
-    x = jnp.pad(pos[:, 0], (0, pad), constant_values=_FAR)[None, :]
-    y = jnp.pad(pos[:, 1], (0, pad), constant_values=_FAR)[None, :]
-    rz = jnp.pad(zw, (0, pad))[None, :]
-    el = jnp.pad(elig.astype(jnp.uint32), (0, pad))[None, :]
+    x = jnp.pad(pos[:, 0], (0, pad), constant_values=_FAR)
+    y = jnp.pad(pos[:, 1], (0, pad), constant_values=_FAR)
+    rz = _as_i32(jnp.pad(zw, (0, pad)))
+    el = jnp.pad(elig.astype(jnp.int32), (0, pad))
     nw, nw_pad = prevw.shape[1], n_pad // 32
-    prevw = jnp.pad(prevw, ((0, pad), (0, nw_pad - nw)))
+    prevw = _as_i32(jnp.pad(prevw, ((0, pad), (0, nw_pad - nw))))
 
-    kernel = functools.partial(
-        _kernel, r_tx2=r_tx2, blk_i=blk_i, n_pad=n_pad,
-    )
-    n_i = n_pad // blk_i
+    kernel = functools.partial(_kernel, r_tx2=r_tx2, blk_i=blk_i)
+    rows = pl.BlockSpec((blk_i, 1), lambda i: (i, 0))
+    planes = pl.BlockSpec((32, nw_pad), lambda i: (0, 0))
+    words = pl.BlockSpec((blk_i, nw_pad), lambda i: (i, 0))
     closew, best_j, has = pl.pallas_call(
         kernel,
-        grid=(n_i,),
-        in_specs=[
-            pl.BlockSpec((1, blk_i), lambda i: (0, i)),       # xi
-            pl.BlockSpec((1, blk_i), lambda i: (0, i)),       # yi
-            pl.BlockSpec((1, n_pad), lambda i: (0, 0)),       # x
-            pl.BlockSpec((1, n_pad), lambda i: (0, 0)),       # y
-            pl.BlockSpec((1, blk_i), lambda i: (0, i)),       # rz_i
-            pl.BlockSpec((1, n_pad), lambda i: (0, 0)),       # rz
-            pl.BlockSpec((1, blk_i), lambda i: (0, i)),       # elig_i
-            pl.BlockSpec((1, n_pad), lambda i: (0, 0)),       # elig
-            pl.BlockSpec((blk_i, nw_pad), lambda i: (i, 0)),  # prevw
-        ],
-        out_specs=[
-            pl.BlockSpec((blk_i, nw_pad), lambda i: (i, 0)),
-            pl.BlockSpec((1, blk_i), lambda i: (0, i)),
-            pl.BlockSpec((1, blk_i), lambda i: (0, i)),
-        ],
+        grid=(n_pad // blk_i,),
+        in_specs=[rows] * 4 + [planes] * 4 + [words],
+        out_specs=[words, rows, rows],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad, nw_pad), jnp.uint32),
-            jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, nw_pad), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(x, y, x, y, rz, rz, el, el, prevw)
-    return closew[:n, :nw], best_j[0, :n], has[0, :n] != 0
+    )(x[:, None], y[:, None], rz[:, None], el[:, None],
+      _bit_planes(x), _bit_planes(y), _bit_planes(rz), _bit_planes(el),
+      prevw)
+    closew = jax.lax.bitcast_convert_type(closew, jnp.uint32)
+    return closew[:n, :nw], best_j[:n, 0], has[:n, 0] != 0
 
 
 # --------------------------------------------------------------------------
@@ -377,11 +401,13 @@ def pairwise_contacts(pos, in_rz, elig, prevw, r_tx2, access=None, *,
 # (``repro.sim.cells.neighbor_lists``) turns it into bounded per-node
 # neighbor lists.
 #
-# The Pallas grid runs one step per interior cell; the 9 neighbor blocks
-# of each input plane are expressed as 9 views of the same array whose
-# index maps add the flattened neighborhood offsets — the border ring
-# makes every offset in-bounds. Like the pairwise kernel, outputs are
-# discrete (packed bits) so kernel and oracle are bitwise comparable.
+# The Pallas grid runs one step per interior grid row. The kernel sees
+# each plane as ``(ncx + 2, cap, NYp)`` — slots on sublanes, the cells of
+# a grid row on lanes — and takes rows cx - 1, cx, cx + 1 as three
+# blocks; the dy = ±1 neighbors are lane rolls of those rows, and the
+# border ring makes every neighbor of an interior cell a real (empty)
+# cell. Like the pairwise kernel, outputs are discrete (packed bits, built
+# bit plane by bit plane) so kernel and oracle are bitwise comparable.
 
 
 _CELL_PLANES = 4            # x, y, zone word, node id
@@ -451,20 +477,38 @@ def cell_close_words_ref(xc, yc, zc, idc, ncx: int, ncy: int, r_tx2):
     )
 
 
-def _cell_kernel(*refs, r_tx2, cap):
-    # refs: 4 planes x 9 neighborhood views (center = offset index 4),
-    # then the output block
-    groups = [refs[p * _NEIGHBORHOOD:(p + 1) * _NEIGHBORHOOD]
-              for p in range(_CELL_PLANES)]
-    out_ref = refs[_CELL_PLANES * _NEIGHBORHOOD]
-    xg, yg, zg, ig = groups
-    xj = jnp.concatenate([r[0] for r in xg])     # (9 * cap,)
-    yj = jnp.concatenate([r[0] for r in yg])
-    zj = jnp.concatenate([r[0] for r in zg])
-    ij = jnp.concatenate([r[0] for r in ig])
-    out_ref[0] = _cell_close(
-        xg[4][0], yg[4][0], zg[4][0], ig[4][0], xj, yj, zj, ij, r_tx2
-    )
+def _cell_kernel(*refs, r_tx2, cap, nwords):
+    # refs: 4 planes x 3 row views (dx = -1, 0, +1), then the output block.
+    # Each view is one padded grid row laid out (cap, NYp): slots on
+    # sublanes, cells on lanes. The dy = +-1 neighbors are lane rolls of a
+    # view (the border ring keeps every interior cell's neighbors in range;
+    # the wrapped lanes only ever feed border columns, which are dropped).
+    views = [[refs[p * 3 + r][0] for r in range(3)]
+             for p in range(_CELL_PLANES)]
+    out_ref = refs[_CELL_PLANES * 3]
+    xc, yc, zc, ic = (v[1] for v in views)            # center row, (cap, NYp)
+    nyp = xc.shape[1]
+    words = [jnp.zeros(xc.shape, jnp.int32) for _ in range(nwords)]
+    for q in range(_NEIGHBORHOOD):
+        dx, dy = q // 3 - 1, q % 3 - 1
+        nb = [v[dx + 1] if dy == 0
+              else pltpu.roll(v[dx + 1], (-dy) % nyp, axis=1)
+              for v in views]
+        for s in range(cap):
+            k = q * cap + s                           # candidate slot index
+            xk, yk, zk, ik = (v[s:s + 1, :] for v in nb)
+            ddx = xc - xk
+            ddy = yc - yk
+            d2 = ddx * ddx + ddy * ddy
+            close = (
+                (d2 <= r_tx2)
+                & ((zc & zk) != 0)
+                & (ic != ik)                          # same id = same node (or
+                & (ik >= 0)                           # both empty, id -1)
+            )
+            words[k // 32] = _or_bit(words[k // 32], close, k % 32)
+    for w in range(nwords):
+        out_ref[0, w] = words[w]
 
 
 @functools.partial(
@@ -474,34 +518,46 @@ def cell_close_words(xc, yc, zc, idc, ncx: int, ncy: int, r_tx2, *,
                      interpret: bool = False):
     """Tiled Pallas 3×3-cell-neighborhood close pass (see block comment).
 
-    One grid step per interior cell; each input plane contributes nine
-    ``(1, cap)`` blocks whose index maps translate the interior cell
-    index to the padded-grid neighbor cell. Pinned bitwise against
+    One grid step per interior grid row ``cx``: each input plane is laid
+    out row-major as ``(ncx + 2, cap, NYp)`` (cells on lanes, padded to a
+    lane multiple) and contributes the three rows ``cx - 1 .. cx + 1`` as
+    ``(1, cap, NYp)`` blocks; the close bits of all cells in the row are
+    built as 32 bit planes per output word. Pinned bitwise against
     :func:`cell_close_words_ref` in ``tests/test_kernels.py``.
     """
     cap = xc.shape[1]
-    offsets = cell_neighborhood_offsets(ncy)
+    nx, ny = ncx + 2, ncy + 2
+    nyp = -(-ny // 128) * 128
     nwords = (_NEIGHBORHOOD * cap + 31) // 32
 
-    def imap(i, off=0):
-        return (padded_cell_id(i // ncy, i % ncy, ncy) + off, 0)
+    def rows(plane, fill):
+        p = plane.reshape(nx, ny, cap).transpose(0, 2, 1)
+        return jnp.pad(p, ((0, 0), (0, 0), (0, nyp - ny)),
+                       constant_values=fill)
 
-    in_specs = []
-    inputs = []
-    for plane in (xc, yc, zc, idc):
-        for off in offsets:
-            in_specs.append(
-                pl.BlockSpec((1, cap), functools.partial(imap, off=off))
-            )
+    planes = (rows(xc, _FAR), rows(yc, _FAR), rows(_as_i32(zc), 0),
+              rows(idc, -1))
+    in_specs, inputs = [], []
+    for plane in planes:
+        for r in range(3):
+            in_specs.append(pl.BlockSpec(
+                (1, cap, nyp), functools.partial(lambda i, r: (i + r, 0, 0),
+                                                 r=r)))
             inputs.append(plane)
 
-    kernel = functools.partial(_cell_kernel, r_tx2=r_tx2, cap=cap)
-    return pl.pallas_call(
+    kernel = functools.partial(_cell_kernel, r_tx2=r_tx2, cap=cap,
+                               nwords=nwords)
+    out = pl.pallas_call(
         kernel,
-        grid=(ncx * ncy,),
+        grid=(ncx,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, cap, nwords), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((ncx * ncy, cap, nwords), jnp.uint32),
+        out_specs=pl.BlockSpec((1, nwords, cap, nyp),
+                               lambda i: (i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((ncx, nwords, cap, nyp), jnp.int32),
         interpret=interpret,
     )(*inputs)
-
+    # interior columns back to the oracle's (cell, slot, word) layout
+    out = out[:, :, :, 1:ncy + 1].transpose(0, 3, 2, 1)
+    return jax.lax.bitcast_convert_type(
+        out.reshape(ncx * ncy, cap, nwords), jnp.uint32
+    )

@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs step-by-step in Python against the same BlockSpec
-tiling, which is the validation contract; on TPU set ``interpret=False``
-(auto-detected by default).
+``interpret=None`` compiles the kernel on a TPU backend. Elsewhere the
+attention and SSD wrappers fall back to interpret mode, which only the
+tests use (they pin the kernels against their references); no simulator
+path calls them.
 """
 
 from __future__ import annotations

@@ -32,7 +32,7 @@ from repro.configs import get_arch_config, list_archs
 from repro.configs.shapes import SHAPES, get_shape
 from repro.launch.input_specs import build_specs
 from repro.launch.mesh import (
-    HBM_BW, ICI_BW, PEAK_FLOPS, make_production_mesh, use_mesh,
+    HBM_BW, ICI_BW, PEAK_FLOPS, make_production_mesh,
 )
 from repro.configs.base import param_count
 
@@ -84,23 +84,6 @@ def roofline_terms(flops_per_dev, bytes_per_dev, coll_bytes_per_dev,
     )
 
 
-def _as_shardings(mesh, specs):
-    """PartitionSpec pytree -> what this jax's ``jit`` shardings accept.
-
-    jax >= 0.6 resolves bare PartitionSpecs against the ambient mesh set by
-    ``jax.set_mesh``; older jax requires concrete ``NamedSharding``s (and
-    rejects ``None`` leaves), so bind them to the mesh here."""
-    if hasattr(jax, "set_mesh"):
-        return specs
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s if isinstance(s, PartitionSpec) else PartitionSpec()),
-        specs,
-        is_leaf=lambda s: s is None or isinstance(s, PartitionSpec),
-    )
-
-
 def run_one(arch: str, shape_name: str, *, multi_pod: bool, mode=None,
             gossip_overrides=None, arch_overrides=None, verbose=True,
             opts=None) -> dict:
@@ -116,11 +99,11 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, mode=None,
                        opts=opts if opts is not None else PerfOpts())
     step = spec.meta["step"]
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             step,
-            in_shardings=_as_shardings(mesh, spec.in_specs),
-            out_shardings=_as_shardings(mesh, spec.out_specs),
+            in_shardings=spec.in_specs,
+            out_shardings=spec.out_specs,
             donate_argnums=spec.donate,
         )
         lowered = jitted.lower(*spec.abstract_args)

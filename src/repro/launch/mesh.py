@@ -5,54 +5,20 @@ Target hardware: TPU v5e pods, 256 chips each (16x16), optionally 2 pods.
   single-pod: (16, 16)      axes ("data", "model")
   multi-pod : (2, 16, 16)   axes ("pod", "data", "model")
 
-Hardware constants for the roofline analysis live here too, plus the
-version-compat helpers ``compat_make_mesh`` / ``use_mesh`` (newer jax
-renamed/added mesh APIs — ``jax.sharding.AxisType`` and ``jax.set_mesh``
-do not exist in older releases; cf. the ``shard_map`` shim in
-``repro.core.gossip``).
+Hardware constants for the roofline analysis live here too. Meshes use
+``AxisType.Auto`` axes (``jax.make_mesh`` defaults to ``Explicit``), so
+sharding stays the compiler's choice inside ``jit``.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
+from jax.sharding import AxisType
 
 __all__ = [
-    "compat_make_mesh", "use_mesh",
     "make_production_mesh", "make_host_mesh",
     "PEAK_FLOPS", "HBM_BW", "ICI_BW",
 ]
-
-
-def compat_make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the installed jax
-    supports them (jax >= 0.5), plain mesh otherwise."""
-    try:
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axis_names)
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` where available (jax >= 0.6); on older releases the
-    physical-mesh context (``with mesh:``) covers the same uses here
-    (shard_map / pjit resource resolution). Wrapped so callers can rely on
-    getting a context manager either way."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-
-    @contextlib.contextmanager
-    def _ctx():
-        with mesh:
-            yield mesh
-
-    return _ctx()
 
 # TPU v5e-class chip (assignment constants)
 PEAK_FLOPS = 197e12   # bf16 FLOP/s per chip
@@ -63,7 +29,7 @@ ICI_BW = 50e9         # bytes/s per ICI link
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -71,4 +37,5 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         raise ValueError(f"need {data * model} devices, have {n}")
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -89,17 +89,24 @@ def _unflatten(spec: TinySpec, theta):
 
 def tiny_logits(spec: TinySpec, theta, x):
     """Logits ``(..., B, C)`` from ``theta (..., D)`` and ``x (B, F)`` (or
-    ``(..., B, F)`` matching theta's leading axes)."""
+    ``(..., B, F)`` matching theta's leading axes).
+
+    The models are defined in float32: contractions pin ``HIGHEST``
+    precision, which the TPU would otherwise run as one bfloat16 pass."""
     theta = theta.astype(jnp.float32)
     x = x.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
     if spec.model == "logreg":
         w, b = _unflatten(spec, theta)
-        return jnp.einsum("...bf,...fc->...bc", x, w) + b[..., None, :]
+        return (jnp.einsum("...bf,...fc->...bc", x, w, precision=hi)
+                + b[..., None, :])
     w1, b1, w2, b2 = _unflatten(spec, theta)
     hdn = jax.nn.relu(
-        jnp.einsum("...bf,...fh->...bh", x, w1) + b1[..., None, :]
+        jnp.einsum("...bf,...fh->...bh", x, w1, precision=hi)
+        + b1[..., None, :]
     )
-    return jnp.einsum("...bh,...hc->...bc", hdn, w2) + b2[..., None, :]
+    return (jnp.einsum("...bh,...hc->...bc", hdn, w2, precision=hi)
+            + b2[..., None, :])
 
 
 def tiny_loss(spec: TinySpec, theta, x, y):

@@ -61,39 +61,17 @@ __all__ = [
 
 
 def shared_barrier(x):
-    """``lax.optimization_barrier`` with a vmap compat shim.
+    """``lax.optimization_barrier``: a materialization point.
 
-    Marks a value as a materialization point: XLA's producer-duplicating
-    fusion otherwise inlines the producing computation into *every*
-    consumer — in a sweep batch that re-computes per-seed-shared
-    intermediates (the pairwise distance matrix, the observer-rank
-    matrix) once per scenario inside each fused per-run consumer,
-    silently undoing the work sharing ``vmap`` set up (measured ~25% of
-    full-sweep wall time for the distance matrix). The barrier is the
-    identity, so results are bit-identical.
-
-    jax 0.4.37 ships no batching rule for the primitive (added upstream
-    later); registering the trivial pass-through rule here is safe — the
-    barrier is identity per operand, so batch dims flow through
-    unchanged. The rule registration reaches into ``jax._src``; if a
-    newer jax moved the primitive (or already batches it), the shim
-    degrades to the identity — the barrier is a pure performance hint,
-    so only fusion quality is lost, never correctness.
+    XLA's producer-duplicating fusion otherwise inlines the producing
+    computation into *every* consumer — in a sweep batch that re-computes
+    per-seed-shared intermediates (the pairwise distance matrix, the
+    observer-rank matrix) once per scenario inside each fused per-run
+    consumer, silently undoing the work sharing ``vmap`` set up (measured
+    ~25% of full-sweep wall time for the distance matrix). The barrier is
+    the identity and batches under ``vmap``, so results are bit-identical
+    (pinned in ``tests/test_sim_queue_ops.py``).
     """
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:  # pragma: no cover - newer jax layouts
-        try:
-            return jax.lax.optimization_barrier(x)
-        except Exception:
-            return x
-
-    if optimization_barrier_p not in batching.primitive_batchers:
-        def _batch_rule(args, dims):
-            return optimization_barrier_p.bind(*args), dims
-
-        batching.primitive_batchers[optimization_barrier_p] = _batch_rule
     return jax.lax.optimization_barrier(x)
 
 

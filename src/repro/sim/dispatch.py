@@ -13,11 +13,16 @@ shared-directory multi-host by construction (workers on any machine that
 mounts the directory can join; today the coordinator spawns them locally):
 
 ``spec.pkl``
-    The pickled sweep definition (params, config, seeds, reduction knobs)
-    plus the sweep fingerprint. Workers rebuild the *identical*
-    :class:`~repro.sim.sweep._SweepSetup` from it, so every process
-    compiles the same chunk program and chunk results are bitwise
-    reproducible wherever they run.
+    The pickled sweep definition (params, config, seeds, reduction knobs).
+    Workers rebuild the *identical* :class:`~repro.sim.sweep._SweepSetup`
+    from it, so every process compiles the same chunk program and chunk
+    results are bitwise reproducible wherever they run.
+``setup.pkl``
+    The first worker to finish its setup publishes it here with the
+    chunk result shapes (``setup_error.json`` instead when the setup
+    raised). The coordinator never initializes a JAX backend — on a TPU
+    host the chip belongs to one process, and that is the worker — so it
+    takes the sweep plan from this file before it enqueues any task.
 ``todo/chunk_{c}.{tag}.task``
     One JSON task per pending chunk attempt. Claiming is a single atomic
     ``os.rename`` of the task file into ``leases/`` — exactly one of any
@@ -74,6 +79,7 @@ the quarantined ones).
 from __future__ import annotations
 
 import dataclasses
+import glob
 import hashlib
 import json
 import os
@@ -211,17 +217,23 @@ def _parse_task_name(name: str) -> tuple[int, int, int]:
     return chunk, int(tag[1:]), 0
 
 
+def _publish(final: str, data: bytes) -> None:
+    """Atomically create ``final`` (write temp + rename), so a reader never
+    sees a half-written file."""
+    tmp = final + f".tmp-{os.getpid()}"
+    with open(tmp, "wb") as f:
+        f.write(data)
+    os.replace(tmp, final)
+
+
 def enqueue_task(queue_dir: str, chunk: int, attempt: int,
                  dup: int = 0) -> str:
-    """Atomically publish a chunk task into ``todo/`` (write temp +
-    rename, so a claimer never sees a half-written task file)."""
+    """Atomically publish a chunk task into ``todo/``."""
     name = _task_name(chunk, attempt, dup) + ".task"
     final = _q(queue_dir, "todo", name)
-    tmp = final + f".tmp-{os.getpid()}"
-    with open(tmp, "w") as f:
-        json.dump({"chunk": chunk, "attempt": attempt, "dup": dup,
-                   "enqueued_at": time.time()}, f)
-    os.replace(tmp, final)
+    _publish(final, json.dumps({"chunk": chunk, "attempt": attempt,
+                                "dup": dup,
+                                "enqueued_at": time.time()}).encode())
     return final
 
 
@@ -363,16 +375,12 @@ def _validate_result(results_dir: str, chunk: int, fp: str,
 def _write_failure(queue_dir: str, chunk: int, attempt: int, dup: int,
                    worker_id: str, exc: BaseException) -> None:
     name = _task_name(chunk, attempt, dup) + ".json"
-    final = _q(queue_dir, "failures", name)
-    tmp = final + f".tmp-{os.getpid()}"
-    with open(tmp, "w") as f:
-        json.dump({
-            "chunk": chunk, "attempt": attempt, "dup": dup,
-            "worker": worker_id, "time": time.time(),
-            "error": repr(exc),
-            "traceback": traceback.format_exc(),
-        }, f, indent=1)
-    os.replace(tmp, final)
+    _publish(_q(queue_dir, "failures", name), json.dumps({
+        "chunk": chunk, "attempt": attempt, "dup": dup,
+        "worker": worker_id, "time": time.time(),
+        "error": repr(exc),
+        "traceback": traceback.format_exc(),
+    }, indent=1).encode())
 
 
 # --------------------------------------------------------------------------
@@ -394,6 +402,31 @@ def _setup_from_spec(spec: dict):
     )
 
 
+def _prepare_worker(queue_dir: str, spec: dict):
+    """Build the sweep setup in this worker and publish it (once) for the
+    coordinator; a setup that raises is published as ``setup_error.json``
+    and re-raised."""
+    import jax
+
+    try:
+        setup = _setup_from_spec(spec)
+        if len(jax.devices()) < setup.plan.n_devices:
+            raise DispatchError(
+                f"worker sees {len(jax.devices())} XLA devices but the "
+                f"sweep plan needs {setup.plan.n_devices} — start workers "
+                "with the same XLA_FLAGS/device topology"
+            )
+        if not os.path.exists(_q(queue_dir, "setup.pkl")):
+            _publish(_q(queue_dir, "setup.pkl"), pickle.dumps(dict(
+                setup=setup, expected=setup.expected_shapes())))
+    except Exception as e:
+        record = {"error": repr(e), "traceback": traceback.format_exc()}
+        _publish(_q(queue_dir, "setup_error.json"),
+                 json.dumps(record).encode())
+        raise
+    return setup
+
+
 def worker_main(queue_dir: str, worker_id: str) -> int:
     """Claim-compute-publish loop of one worker process.
 
@@ -404,27 +437,17 @@ def worker_main(queue_dir: str, worker_id: str) -> int:
     """
     import jax
 
+    from repro.launch.cache import enable_compile_cache
+    from repro.sim import sweep
+
     spec = _load_spec(queue_dir)
     policy: RetryPolicy = spec["policy"]
-    fp: str = spec["fingerprint"]
     results_dir: str = spec.get("results_dir") or _q(queue_dir, "results")
+    enable_compile_cache(spec.get("xla_cache_dir"))
 
-    cache_dir = spec.get("xla_cache_dir")
-    if cache_dir:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except AttributeError:  # older jax: knob absent, cache still works
-            pass
-
-    setup = _setup_from_spec(spec)
-    if len(jax.devices()) < setup.plan.n_devices:
-        raise DispatchError(
-            f"worker sees {len(jax.devices())} XLA devices but the sweep "
-            f"plan needs {setup.plan.n_devices} — start workers with the "
-            "same XLA_FLAGS/device topology as the coordinator"
-        )
+    setup = _prepare_worker(queue_dir, spec)
+    fp = sweep._setup_fingerprint(setup, spec["seeds"])
+    keys = setup.keys()
     chaos: list[dict] = spec.get("chaos") or []
     worker_fn = None  # compile lazily on the first claimed task
 
@@ -463,7 +486,7 @@ def worker_main(queue_dir: str, worker_id: str) -> int:
                 warnings.filterwarnings(
                     "ignore", message="Some donated buffers were not usable"
                 )
-                out = worker_fn(setup.keys, setup.chunk_params(chunk))
+                out = worker_fn(keys, setup.chunk_params(chunk))
             hc = jax.tree_util.tree_map(np.asarray, out)
             if directive is not None and directive["action"] == "corrupt":
                 # a torn/garbage write at the exact publish point: the
@@ -566,6 +589,63 @@ class _WorkerPool:
                 p.stderr.close()
 
 
+#: PCI device ids of TPU chips (Google's vendor id also covers its NICs).
+_TPU_PCI_IDS = frozenset(
+    {"0x0027", "0x0056", "0x005e", "0x0062", "0x0063", "0x006f", "0x0076"})
+
+
+def _tpu_chips() -> int:
+    """TPU chips attached to this host, counted from PCI (the probe JAX's
+    own TPU detection uses) so the coordinator initializes no JAX backend.
+    0 where ``JAX_PLATFORMS`` leaves the TPU out."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    n = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        try:
+            with open(vendor) as f:
+                if f.read().strip() != "0x1ae0":
+                    continue
+            with open(os.path.join(os.path.dirname(vendor), "device")) as f:
+                n += f.read().strip() in _TPU_PCI_IDS
+        except OSError:
+            continue
+    return n
+
+
+def _await_setup(queue_dir: str, pool: _WorkerPool, policy: RetryPolicy):
+    """Wait for a worker to publish the sweep setup: ``(setup, expected)``.
+
+    Raises :class:`DispatchError` with the worker's traceback when the
+    setup failed there, and when no worker can publish it."""
+    t0 = time.monotonic()
+    while True:
+        err = _q(queue_dir, "setup_error.json")
+        if os.path.exists(err):
+            with open(err) as f:
+                rec = json.load(f)
+            raise DispatchError(
+                f"sweep setup failed in a worker: {rec['error']}\n"
+                f"{rec['traceback']}")
+        try:
+            with open(_q(queue_dir, "setup.pkl"), "rb") as f:
+                got = pickle.load(f)
+            return got["setup"], got["expected"]
+        except FileNotFoundError:
+            pass
+        pool.reap_and_respawn()
+        if pool.alive() == 0 and pool.respawns >= policy.max_respawns:
+            raise DispatchError(
+                "no live workers and respawn budget exhausted before any "
+                "worker published the sweep setup")
+        if time.monotonic() - t0 > policy.stall_timeout_s:
+            raise DispatchError(
+                f"no worker published the sweep setup within "
+                f"{policy.stall_timeout_s}s")
+        time.sleep(policy.poll_s)
+
+
 def _spawn_env(n_devices: int | None) -> dict:
     """Worker environment: inherit, make ``repro`` importable by absolute
     path (the parent may run with a relative ``PYTHONPATH``), and pin the
@@ -623,9 +703,17 @@ def run_dispatched(
                   to the workers — the chaos harness. Directives match
                   non-duplicate tasks by (chunk, attempt).
       xla_cache_dir: persistent XLA compile-cache directory shared by the
-                  workers (default ``{queue_dir}/xla_cache``) — a respawned
-                  worker (or a second sweep over the same config) skips
-                  recompilation, which is most of a fresh process's cost.
+                  workers (default: ``repro.launch.cache``'s — the
+                  ``JAX_COMPILATION_CACHE_DIR`` variable where set, which
+                  also overrides this argument, else ``.jax_cache/`` in
+                  the checkout) — a respawned worker (or a second sweep
+                  over the same config) skips recompilation, which is most
+                  of a fresh process's cost.
+
+    The coordinator (the calling process) initializes no JAX backend: the
+    workers build the sweep setup and publish it (module docstring). A
+    TPU host runs one worker, which shards its chunks over all the host's
+    chips; more raise :class:`DispatchError` before anything starts.
 
     Returns:
       ``BatchSimOutputs`` / :class:`~repro.sim.sweep.SweepSummary` with
@@ -639,11 +727,15 @@ def run_dispatched(
         max_attempts=3)
     if workers < 1:
         raise ValueError("workers must be >= 1")
-    setup = sweep._prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size,
-                           quantiles, tau_grid, n_devices)
-    plan = setup.plan
-    fp = sweep._setup_fingerprint(setup, seeds)
-    expected = setup.expected_shapes()
+    chips = _tpu_chips()
+    if chips and workers > 1:
+        raise DispatchError(
+            f"workers={workers} on a host with {chips} TPU chip(s): a "
+            "worker process opens every chip of its host and a chip "
+            "belongs to one process, so a TPU host runs one dispatch "
+            "worker (its plan shards over all of the host's chips)"
+        )
+    ps, _, _ = sweep._validate(ps, reduce, tau_grid)
 
     own_queue = queue_dir is None and checkpoint_dir is None
     if queue_dir is None:
@@ -656,44 +748,13 @@ def run_dispatched(
                    else _q(queue_dir, "results"))
     _init_queue(queue_dir)
     os.makedirs(results_dir, exist_ok=True)
-    if xla_cache_dir is None:
-        xla_cache_dir = _q(queue_dir, "xla_cache")
-    os.makedirs(xla_cache_dir, exist_ok=True)
     done_marker = _q(queue_dir, "DONE")
-    if os.path.exists(done_marker):
-        os.remove(done_marker)
-
-    # ---- publish the sweep spec -----------------------------------------
-    if isinstance(ps, sweep.FGParams):
-        ps = [ps]
-    spec = dict(
-        ps=tuple(ps), cfg=cfg, seeds=tuple(seeds), reduce=reduce,
-        warmup_frac=warmup_frac, chunk_size=chunk_size,
-        quantiles=tuple(quantiles), tau_grid=tau_grid, n_devices=n_devices,
-        fingerprint=fp, policy=policy, chaos=list(chaos or ()),
-        xla_cache_dir=xla_cache_dir, results_dir=results_dir,
-    )
-    spec_tmp = _q(queue_dir, f"spec.pkl.tmp-{os.getpid()}")
-    with open(spec_tmp, "wb") as f:
-        pickle.dump(spec, f)
-    os.replace(spec_tmp, _q(queue_dir, "spec.pkl"))
-
-    # ---- resume: accept pre-existing valid results ----------------------
-    results: dict[int, dict] = {}
-    telemetry: dict = {
-        "chunks": {c: {"attempts": 0, "requeues": 0, "duplicates": 0}
-                   for c in range(plan.n_chunks)},
-        "expired_leases": 0, "corrupt_results": 0, "worker_failures": 0,
-        "respawns": 0, "quarantine": {},
-    }
-    if resume:
-        for c, tree in sweep._load_chunks(
-                results_dir, fp, plan.n_chunks,
-                expected=expected).items():
-            results[c] = tree
-            telemetry["chunks"][c]["resumed"] = True
-    # drop stale queue state from a previous (killed) coordinator: tasks,
-    # leases and failure records are per-run bookkeeping, results are not
+    # drop stale queue state from a previous (killed) coordinator before
+    # any worker starts: tasks, leases, failure records and the published
+    # setup are per-run bookkeeping, results are not
+    for name in ("DONE", "setup.pkl", "setup_error.json"):
+        if os.path.exists(_q(queue_dir, name)):
+            os.remove(_q(queue_dir, name))
     for d in ("todo", "leases", "failures"):
         for name in os.listdir(_q(queue_dir, d)):
             try:
@@ -701,68 +762,92 @@ def run_dispatched(
             except FileNotFoundError:  # pragma: no cover
                 pass
 
-    attempts = {c: 0 for c in range(plan.n_chunks)}     # failures so far
-    backoff_until: dict[int, float] = {}                # chunk -> mono time
-    pending_enqueue = {c: 0 for c in range(plan.n_chunks) if c not in results}
-    claim_t: dict[tuple[int, int, int], float] = {}     # task -> mono time
-    first_enq: dict[int, float] = {}
-    latencies: list[float] = []
-    quarantined: dict[int, dict] = {}
-    seen_failures: set[str] = set()
-    invalid_results: set[int] = set()
-
-    now = time.monotonic
-    for c in pending_enqueue:
-        enqueue_task(queue_dir, c, 0)
-        first_enq[c] = now()
-    enqueued = {c: 0 for c in pending_enqueue}  # chunk -> current attempt
-    pending_enqueue = {}
-
+    # ---- publish the sweep spec, start the workers ------------------------
+    spec = dict(
+        ps=tuple(ps), cfg=cfg, seeds=tuple(seeds), reduce=reduce,
+        warmup_frac=warmup_frac, chunk_size=chunk_size,
+        quantiles=tuple(quantiles), tau_grid=tau_grid, n_devices=n_devices,
+        policy=policy, chaos=list(chaos or ()),
+        xla_cache_dir=xla_cache_dir, results_dir=results_dir,
+    )
+    _publish(_q(queue_dir, "spec.pkl"), pickle.dumps(spec))
     pool = _WorkerPool(queue_dir, workers, policy, _spawn_env(n_devices))
-    last_progress = now()
-
-    def outstanding():
-        return [c for c in range(plan.n_chunks)
-                if c not in results and c not in quarantined]
-
-    def fail_attempt(c: int, reason: str, *, requeue_kind: str):
-        """Charge the chunk an attempt; back off + re-enqueue or quarantine."""
-        nonlocal last_progress
-        attempts[c] += 1
-        last_progress = now()
-        if attempts[c] >= policy.max_attempts:
-            record = {
-                "chunk": c, "attempts": attempts[c], "reason": reason,
-                "time": time.time(),
-            }
-            fail_file = None
-            for name in sorted(os.listdir(_q(queue_dir, "failures")),
-                               reverse=True):
-                if name.startswith(f"chunk_{c:05d}."):
-                    fail_file = _q(queue_dir, "failures", name)
-                    break
-            if fail_file is not None:
-                try:
-                    with open(fail_file) as f:
-                        record["last_failure"] = json.load(f)
-                except (OSError, ValueError):  # pragma: no cover
-                    pass
-            qpath = _q(queue_dir, "quarantine", f"chunk_{c:05d}.json")
-            with open(qpath + ".tmp", "w") as f:
-                json.dump(record, f, indent=1)
-            os.replace(qpath + ".tmp", qpath)
-            quarantined[c] = record
-            telemetry["quarantine"][c] = record
-            warnings.warn(
-                f"dispatch chunk {c} quarantined after {attempts[c]} "
-                f"attempts: {reason}"
-            )
-        else:
-            delay = policy.backoff(attempts[c], key=f"{fp}:{c}")
-            backoff_until[c] = now() + delay
-            telemetry["chunks"][c]["requeues"] += 1
-
     try:
+        setup, expected = _await_setup(queue_dir, pool, policy)
+        plan = setup.plan
+        fp = sweep._setup_fingerprint(setup, seeds)
+
+        # ---- resume: accept pre-existing valid results ------------------
+        results: dict[int, dict] = {}
+        telemetry: dict = {
+            "chunks": {c: {"attempts": 0, "requeues": 0, "duplicates": 0}
+                       for c in range(plan.n_chunks)},
+            "expired_leases": 0, "corrupt_results": 0,
+            "worker_failures": 0, "respawns": 0, "quarantine": {},
+        }
+        if resume:
+            for c, tree in sweep._load_chunks(
+                    results_dir, fp, plan.n_chunks,
+                    expected=expected).items():
+                results[c] = tree
+                telemetry["chunks"][c]["resumed"] = True
+
+        attempts = {c: 0 for c in range(plan.n_chunks)}  # failures so far
+        backoff_until: dict[int, float] = {}             # chunk -> mono time
+        claim_t: dict[tuple[int, int, int], float] = {}  # task -> mono time
+        first_enq: dict[int, float] = {}
+        latencies: list[float] = []
+        quarantined: dict[int, dict] = {}
+        seen_failures: set[str] = set()
+        invalid_results: set[int] = set()
+
+        now = time.monotonic
+        for c in range(plan.n_chunks):
+            if c not in results:
+                enqueue_task(queue_dir, c, 0)
+                first_enq[c] = now()
+        last_progress = now()
+
+        def outstanding():
+            return [c for c in range(plan.n_chunks)
+                    if c not in results and c not in quarantined]
+
+        def fail_attempt(c: int, reason: str, *, requeue_kind: str):
+            """Charge the chunk an attempt; back off + re-enqueue or
+            quarantine."""
+            nonlocal last_progress
+            attempts[c] += 1
+            last_progress = now()
+            if attempts[c] >= policy.max_attempts:
+                record = {
+                    "chunk": c, "attempts": attempts[c], "reason": reason,
+                    "time": time.time(),
+                }
+                fail_file = None
+                for name in sorted(os.listdir(_q(queue_dir, "failures")),
+                                   reverse=True):
+                    if name.startswith(f"chunk_{c:05d}."):
+                        fail_file = _q(queue_dir, "failures", name)
+                        break
+                if fail_file is not None:
+                    try:
+                        with open(fail_file) as f:
+                            record["last_failure"] = json.load(f)
+                    except (OSError, ValueError):  # pragma: no cover
+                        pass
+                _publish(_q(queue_dir, "quarantine", f"chunk_{c:05d}.json"),
+                         json.dumps(record, indent=1).encode())
+                quarantined[c] = record
+                telemetry["quarantine"][c] = record
+                warnings.warn(
+                    f"dispatch chunk {c} quarantined after {attempts[c]} "
+                    f"attempts: {reason}"
+                )
+            else:
+                delay = policy.backoff(attempts[c], key=f"{fp}:{c}")
+                backoff_until[c] = now() + delay
+                telemetry["chunks"][c]["requeues"] += 1
+
         while outstanding():
             progressed = False
 
@@ -907,7 +992,6 @@ def run_dispatched(
                     continue
                 if now() >= t_ok:
                     backoff_until.pop(c)
-                    enqueued[c] = attempts[c]
                     enqueue_task(queue_dir, c, attempts[c])
                     first_enq.setdefault(c, now())
 
@@ -927,9 +1011,7 @@ def run_dispatched(
                 )
             time.sleep(policy.poll_s)
     finally:
-        with open(done_marker + ".tmp", "w") as f:
-            f.write("done")
-        os.replace(done_marker + ".tmp", done_marker)
+        _publish(done_marker, b"done")
         pool.shutdown()
 
     host_chunks = []

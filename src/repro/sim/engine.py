@@ -285,10 +285,11 @@ def dynamic_params(p: FGParams) -> dict:
 
 
 def stack_dynamic_params(ps: Sequence[FGParams]) -> dict:
-    """Stack per-scenario dynamic params into leading-axis arrays."""
+    """Stack per-scenario dynamic params into leading-axis float32 arrays
+    (host numpy: building a sweep's inputs touches no device)."""
     dicts = [dynamic_params(p) for p in ps]
     return {
-        k: jnp.asarray([d[k] for d in dicts], dtype=jnp.float32)
+        k: np.asarray([d[k] for d in dicts], dtype=np.float32)
         for k in dicts[0]
     }
 

@@ -183,8 +183,10 @@ class LearnTask:
 
 
 def _labels(key, lc: LearnConfig, x, w_true):
-    """Teacher labels: ``argmax(x W* + σ g)`` (σ = 0 → noiseless)."""
-    logits = x @ w_true
+    """Teacher labels: ``argmax(x W* + σ g)`` (σ = 0 → noiseless), with the
+    float32 product the task defines (``HIGHEST``: the TPU's default
+    matmul precision would round the inputs to bfloat16)."""
+    logits = jnp.matmul(x, w_true, precision=jax.lax.Precision.HIGHEST)
     if lc.label_noise > 0.0:
         logits = logits + lc.label_noise * jax.random.normal(
             key, logits.shape, jnp.float32

@@ -12,12 +12,11 @@ grids (``n_scenarios % n_devices != 0``) and seed-heavy sweeps (many
 seeds, few scenarios) parallelize instead of silently falling back to one
 device. Both axes are padded with repeats of their last row (work items
 are independent SPMD rows, so pad items change nothing and are sliced
-off) and sharded over a 2-D device mesh built with
-``repro.launch.mesh.compat_make_mesh``; partition specs come from the
-``sweep_scenario`` / ``sweep_seed`` logical axes in
-``repro.sharding.logical.SWEEP_RULES``. The (scenario, seed) *structure*
-of each device block is deliberately preserved rather than physically
-flattened to one axis: everything in the per-slot program that depends
+off) and sharded over a 2-D device mesh (``jax.make_mesh``, Auto axes);
+partition specs come from the ``sweep_scenario`` / ``sweep_seed`` logical
+axes in ``repro.sharding.logical.SWEEP_RULES``. The (scenario, seed)
+*structure* of each device block is deliberately preserved rather than
+physically flattened to one axis: everything in the per-slot program that depends
 only on the per-seed PRNG chain — mobility, RZ membership, the O(N²)
 distance matrix, observer scores — is computed once per seed and
 broadcast across the scenario axis by ``vmap``; a physically flattened
@@ -61,7 +60,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.meanfield import FGParams
-from repro.launch.mesh import compat_make_mesh
 from repro.sharding.logical import SWEEP_RULES, spec_for
 from repro.sim.engine import (
     BatchSimOutputs, SimConfig, _check_params, _run, _sample_times,
@@ -313,14 +311,26 @@ def _chunk_worker(cfg: SimConfig, M: int, plan: SweepPlan, reduce: str,
     / ``sweep_seed`` logical axes and the per-chunk parameter buffers are
     donated — each chunk's arrays are dead after its dispatch, so XLA may
     reuse their memory for the scan carry and outputs of the same step.
+    The program runs under ``shard_map``: every (scenario, seed) run is
+    independent, so each device runs the per-run program on its own block
+    of the grid — and the Pallas kernels inside it, which the compiler
+    cannot partition, see device-local shapes.
     """
-    mesh = compat_make_mesh(plan.mesh_shape, ("sweep_scenario", "sweep_seed"))
+    mesh = jax.make_mesh(plan.mesh_shape, ("sweep_scenario", "sweep_seed"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     chunk_p, pad_r = plan.chunk_scenarios, plan.pad_seeds
     scen_spec = spec_for(mesh, ("sweep_scenario",), (chunk_p,), SWEEP_RULES)
     seed_spec = spec_for(mesh, ("sweep_seed", None), (pad_r, 2), SWEEP_RULES)
+    # every output leads with the (scenario, seed) grid axes
+    out_spec = jax.sharding.PartitionSpec(scen_spec[0], seed_spec[0])
+    body = jax.shard_map(
+        _worker_fn(cfg, M, reduce, s0, qs, tau), mesh=mesh,
+        in_specs=(seed_spec, {k: scen_spec for k in p_keys}),
+        out_specs=out_spec, check_vma=False,
+    )
 
     return jax.jit(
-        _worker_fn(cfg, M, reduce, s0, qs, tau),
+        body,
         in_shardings=(
             jax.sharding.NamedSharding(mesh, seed_spec),
             {k: jax.sharding.NamedSharding(mesh, scen_spec) for k in p_keys},
@@ -329,11 +339,11 @@ def _chunk_worker(cfg: SimConfig, M: int, plan: SweepPlan, reduce: str,
     )
 
 
-def _pad_rows(arr: jnp.ndarray, to: int) -> jnp.ndarray:
+def _pad_rows(arr: np.ndarray, to: int) -> np.ndarray:
     pad = to - arr.shape[0]
     if pad == 0:
         return arr
-    return jnp.concatenate([arr, jnp.repeat(arr[-1:], pad, axis=0)])
+    return np.concatenate([arr, np.repeat(arr[-1:], pad, axis=0)])
 
 
 def _sweep_fingerprint(cfg, M, plan, reduce, s0, qs, tau, seeds,
@@ -451,7 +461,9 @@ class _SweepSetup:
     once by :func:`_prepare`; the dispatcher pickles the *inputs* (ps, cfg,
     seeds, knobs) and each worker rebuilds this identically, so every
     process compiles the same chunk program and produces bitwise-identical
-    results."""
+    results. It holds host data only (the PRNG keys are made on demand by
+    :meth:`keys`), so a worker can hand it to the dispatch coordinator,
+    which never touches a device."""
 
     cfg: SimConfig
     M: int
@@ -464,7 +476,12 @@ class _SweepSetup:
     key_tau: tuple
     p_keys: tuple
     p_stack: dict          # padded parameter stack (scenario axis)
-    keys: jnp.ndarray      # padded PRNG keys (seed axis)
+    seeds: tuple           # padded seeds (seed axis)
+
+    def keys(self) -> jnp.ndarray:
+        """The padded seed axis as PRNG keys (the setup's one device op)."""
+        return jax.vmap(jax.random.PRNGKey)(
+            jnp.asarray(self.seeds, jnp.uint32))
 
     def worker(self):
         return _chunk_worker(self.cfg, self.M, self.plan, self.reduce,
@@ -481,17 +498,35 @@ class _SweepSetup:
         compiles, runs, or touches the jit cache."""
         fn = _worker_fn(self.cfg, self.M, self.reduce, self.key_s0,
                         self.key_qs, self.key_tau)
-        return dict(jax.eval_shape(fn, self.keys, self.chunk_params(0)))
+        return dict(jax.eval_shape(fn, self.keys(), self.chunk_params(0)))
 
 
-def _prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size, quantiles,
-             tau_grid, n_devices) -> _SweepSetup:
-    """Validate and normalize a sweep definition into a :class:`_SweepSetup`."""
+def _validate(ps, reduce, tau_grid) -> tuple[list, int, tuple]:
+    """Check a sweep definition: ``(scenarios, M, key_tau)``. Needs no
+    device, so the dispatch coordinator runs it before any worker starts."""
     if isinstance(ps, FGParams):
         ps = [ps]
     if reduce not in REDUCERS:
         raise ValueError(f"unknown reduce mode {reduce!r}; known: {REDUCERS}")
     M = _check_params(ps)
+    key_tau = ()
+    if reduce == "o_tau":
+        if tau_grid is None:
+            raise ValueError('reduce="o_tau" needs a tau_grid')
+        tau_grid = np.asarray(tau_grid, np.float64)
+        dtaus = np.diff(tau_grid)
+        if len(tau_grid) < 2 or not np.allclose(dtaus, dtaus[0]):
+            raise ValueError("tau_grid must be a uniform grid")
+        key_tau = (len(tau_grid), float(tau_grid[1] - tau_grid[0]))
+    return list(ps), M, key_tau
+
+
+def _prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size, quantiles,
+             tau_grid, n_devices) -> _SweepSetup:
+    """Validate and normalize a sweep definition into a :class:`_SweepSetup`.
+
+    Touches JAX only to count devices when ``n_devices`` is None."""
+    ps, M, key_tau = _validate(ps, reduce, tau_grid)
     plan = plan_sweep(len(ps), len(seeds), n_devices=n_devices,
                       chunk_size=chunk_size)
 
@@ -499,10 +534,8 @@ def _prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size, quantiles,
         k: _pad_rows(v, plan.pad_scenarios)
         for k, v in stack_dynamic_params(ps).items()
     }
-    keys = _pad_rows(
-        jax.vmap(jax.random.PRNGKey)(jnp.asarray(list(seeds), jnp.uint32)),
-        plan.pad_seeds,
-    )
+    seeds = [int(s) for s in seeds]
+    seeds += seeds[-1:] * (plan.pad_seeds - len(seeds))
 
     n_samples = cfg.n_slots // cfg.sample_every
     wf = cfg.warmup_frac if warmup_frac is None else warmup_frac
@@ -513,20 +546,10 @@ def _prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size, quantiles,
     # the unused knobs can't trigger a spurious recompilation
     key_s0 = s0 if reduce in ("mean", "quantiles", "o_tau") else 0
     key_qs = tuple(quantiles) if reduce == "quantiles" else ()
-    if reduce == "o_tau":
-        if tau_grid is None:
-            raise ValueError('reduce="o_tau" needs a tau_grid')
-        tau_grid = np.asarray(tau_grid, np.float64)
-        dtaus = np.diff(tau_grid)
-        if len(tau_grid) < 2 or not np.allclose(dtaus, dtaus[0]):
-            raise ValueError("tau_grid must be a uniform grid")
-        key_tau = (len(tau_grid), float(tau_grid[1] - tau_grid[0]))
-    else:
-        key_tau = ()
     return _SweepSetup(
         cfg=cfg, M=M, plan=plan, reduce=reduce, quantiles=tuple(quantiles),
         s0=s0, key_s0=key_s0, key_qs=key_qs, key_tau=key_tau,
-        p_keys=tuple(sorted(p_stack)), p_stack=p_stack, keys=keys,
+        p_keys=tuple(sorted(p_stack)), p_stack=p_stack, seeds=tuple(seeds),
     )
 
 
@@ -706,8 +729,9 @@ def run(
                   set).
       xla_cache_dir: persistent XLA compile-cache directory shared by the
                   dispatcher's worker processes (default:
-                  ``{queue_dir}/xla_cache``) — a warm cache makes a fresh
-                  worker load the chunk program instead of recompiling.
+                  ``repro.launch.cache.enable_compile_cache``'s) — a warm
+                  cache makes a fresh worker load the chunk program
+                  instead of recompiling.
 
     Returns:
       ``BatchSimOutputs`` for ``reduce="trace"`` — with the extra
@@ -728,6 +752,7 @@ def run(
     setup = _prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size,
                      quantiles, tau_grid, n_devices)
     plan = setup.plan
+    keys = setup.keys()
 
     worker_cell: list = []
 
@@ -745,7 +770,7 @@ def run(
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable"
             )
-            return worker_cell[0](setup.keys, p_chunk)
+            return worker_cell[0](keys, p_chunk)
 
     devices_used = 0
     failed: list[int] = []

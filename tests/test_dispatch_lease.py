@@ -194,3 +194,84 @@ def test_retry_policy_validation():
         RetryPolicy(max_attempts=0)
     with pytest.raises(ValueError):
         RetryPolicy(heartbeat_s=2.0, lease_ttl_s=1.0)
+
+
+# --------------------------------------------------------------------------
+# the coordinator stays off the device
+# --------------------------------------------------------------------------
+
+
+_NO_BACKEND_COORDINATOR = r"""
+import sys
+from jax._src import xla_bridge
+from repro.configs.fg_paper import paper_params
+from repro.sim import SimConfig, dispatch
+
+seen = []
+spawn = dispatch._WorkerPool.spawn
+
+def recording_spawn(self):
+    seen.append(xla_bridge.backends_are_initialized())
+    return spawn(self)
+
+dispatch._WorkerPool.spawn = recording_spawn
+out = dispatch.run_dispatched(
+    [paper_params(lam=0.1, M=1), paper_params(lam=0.2, M=1)],
+    SimConfig(n_nodes=40, n_slots=160, sample_every=8), (0, 1),
+    reduce="mean", chunk_size=1, workers=1, queue_dir=sys.argv[1])
+assert seen and not any(seen), seen
+assert not xla_bridge.backends_are_initialized()
+assert out.coverage.all() and out.devices_used == 1
+print("NO-BACKEND-OK", out.stats["availability"].shape)
+"""
+
+
+def _src_env():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in sys.path if p) + os.pathsep + env.get("PYTHONPATH", "")
+    return env
+
+
+def test_coordinator_spawns_workers_without_a_jax_backend(tmp_path):
+    """On a TPU host the chip belongs to one process: the coordinator must
+    not have initialized a JAX backend when it starts a worker (nor later —
+    the workers publish the sweep setup it needs)."""
+    out = subprocess.run(
+        [sys.executable, "-c", _NO_BACKEND_COORDINATOR, str(tmp_path / "q")],
+        env=_src_env(), capture_output=True, text=True, timeout=300,
+    )
+    assert "NO-BACKEND-OK (2, 2, 1)" in out.stdout, out.stdout + out.stderr
+
+
+def test_tpu_host_refuses_a_second_worker_before_spawning(monkeypatch,
+                                                          tmp_path):
+    from repro.configs.fg_paper import paper_params
+    from repro.sim import SimConfig
+
+    assert dispatch._tpu_chips() == 0  # JAX_PLATFORMS=cpu hides any TPU
+    monkeypatch.setattr(dispatch, "_tpu_chips", lambda: 4)
+
+    def no_spawn(self):
+        raise AssertionError("a worker was spawned")
+
+    monkeypatch.setattr(dispatch._WorkerPool, "spawn", no_spawn)
+    with pytest.raises(dispatch.DispatchError, match="one dispatch worker"):
+        dispatch.run_dispatched(
+            [paper_params(lam=0.1, M=1)], SimConfig(n_nodes=40, n_slots=160),
+            (0,), reduce="mean", workers=2, queue_dir=str(tmp_path / "q"))
+
+
+def test_worker_setup_failure_surfaces_in_the_coordinator(tmp_path):
+    """A sweep setup that raises in the worker (here: a plan for more
+    devices than the worker sees) fails the dispatch with the worker's
+    error, instead of respawning workers until the budget runs out."""
+    from repro.configs.fg_paper import paper_params
+    from repro.sim import SimConfig
+
+    with pytest.raises(dispatch.DispatchError,
+                       match="sweep setup failed in a worker.*XLA devices"):
+        dispatch.run_dispatched(
+            [paper_params(lam=0.1, M=1)], SimConfig(n_nodes=40, n_slots=160),
+            (0,), reduce="mean", workers=1, n_devices=64,
+            queue_dir=str(tmp_path / "q"))

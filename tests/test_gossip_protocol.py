@@ -24,8 +24,8 @@ def _run(body: str):
         from repro.core.gossip import (GossipConfig, init_gossip_state,
                                        build_gossip_round, hypercube_matchings,
                                        random_matchings)
-        from repro.launch.mesh import compat_make_mesh, use_mesh
-        mesh = compat_make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         R = 8
         def put(t, s):
             return jax.device_put(t, NamedSharding(mesh, s))
@@ -58,7 +58,7 @@ def test_uniform_hypercube_reaches_consensus():
                            merge_policy="uniform")
         fn, _ = build_gossip_round(mesh, specs, cfg)
         st = jax.tree.map(lambda x: put(x, P("data")), init_gossip_state(R))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             for r in range(3):   # log2(8) rounds -> exact consensus
                 params, st = fn(params, st, default, r)
         w = np.asarray(params["w"])
@@ -79,7 +79,7 @@ def test_gossip_preserves_mean_and_reduces_variance():
         fn, _ = build_gossip_round(mesh, specs, cfg)
         st = jax.tree.map(lambda x: put(x, P("data")), init_gossip_state(R))
         w0 = np.asarray(params["w"])
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             for r in range(6):
                 params, st = fn(params, st, default, r)
         w = np.asarray(params["w"])
@@ -101,7 +101,7 @@ def test_busy_and_failure_gates_block_merging():
         fn, _ = build_gossip_round(mesh, specs, cfg)
         st = jax.tree.map(lambda x: put(x, P("data")), init_gossip_state(R))
         w0 = np.asarray(params["w"])
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             for r in range(4):
                 params, st = fn(params, st, default, r)
         np.testing.assert_allclose(np.asarray(params["w"]), w0)
@@ -119,7 +119,7 @@ def test_churn_resets_to_default():
                            churn_prob=1.0)   # every replica churns
         fn, _ = build_gossip_round(mesh, specs, cfg)
         st = jax.tree.map(lambda x: put(x, P("data")), init_gossip_state(R))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             params, st = fn(params, st, default, 0)
         assert np.allclose(np.asarray(params["w"]), 0.0)
         assert np.allclose(np.asarray(st["count"]), 0.0)
@@ -138,7 +138,7 @@ def test_segmented_gossip_touches_only_one_segment():
         fn, _ = build_gossip_round(mesh, specs, cfg)
         st = jax.tree.map(lambda x: put(x, P("data")), init_gossip_state(R))
         w0 = np.asarray(params["w"])
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             params, st = fn(params, st, default, 0)  # round 0 -> segment 0
         w = np.asarray(params["w"])
         # per-replica leaf is 12 long -> segment = 4 elements
@@ -170,7 +170,7 @@ def test_gossip_training_beats_no_communication():
             return w - 0.2 * g
 
         w_iso = params["w"]
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             for r in range(30):
                 params = {"w": local_step(params["w"], centers)}
                 w_iso = local_step(w_iso, centers)
@@ -212,7 +212,7 @@ def test_zero_count_obs_merge_is_symmetric_average():
         # all counts zero: the obs_count weights must fall back to 0.5
         st = jax.tree.map(lambda x: put(x, P("data")), init_gossip_state(R))
         w0 = np.asarray(params["w"])
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             params, st = fn(params, st, default, 0)
         w = np.asarray(params["w"])
         # round 0 of the hypercube pairs i <-> i^1: exact 0.5/0.5 average

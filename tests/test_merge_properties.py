@@ -128,10 +128,8 @@ def test_count_clip_bounds_metadata_liar_weight(seed):
     the capped weights, and the peer's share never exceeds
     ``cap / (own + cap)`` — the metadata-liar hijack is bounded.
 
-    Counts are drawn from the realistic domain (0, or >= 1 — observation
-    tallies): for fractional sub-unit totals the zero-count fallback's
-    denominator floor deliberately trades proportionality for the
-    symmetric-at-zero merge, and the proportional bound doesn't apply."""
+    Own counts are drawn from the realistic domain (0, or >= 1 —
+    observation tallies)."""
     rng = np.random.default_rng(seed + 1000)
     own = float(10.0 ** rng.uniform(0, 4)) if seed % 5 else 0.0
     claimed = float(10.0 ** rng.uniform(-1, 9))

@@ -4,12 +4,35 @@ ascending free-slot fill, silent drops at capacity, FIFO service order, and
 non-preemptive merge-over-train priority."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.sim.compute import (
-    advance_timers, enqueue_ascending, pack_mask, pick_next_jobs, unpack_mask,
+    advance_timers, enqueue_ascending, pack_mask, pick_next_jobs,
+    shared_barrier, unpack_mask,
 )
+
+
+def test_shared_barrier_is_bitwise_identity_under_nested_vmap():
+    """The barrier batches under vmap — per run, and over a per-seed value
+    shared across scenarios (``in_axes=None``) — and returns its operands
+    bit for bit, special floats included."""
+    x = np.array(jax.random.normal(jax.random.PRNGKey(0), (3, 4, 6)))
+    x[0, 0, :4] = [np.nan, -0.0, np.inf, -np.inf]
+    words = np.asarray(
+        jax.random.bits(jax.random.PRNGKey(1), (4, 5), jnp.uint32))
+
+    def per_run(a, w):
+        return shared_barrier((a, w))
+
+    over_seeds = jax.vmap(per_run, in_axes=(0, 0))
+    f = jax.jit(jax.vmap(over_seeds, in_axes=(0, None)))
+    ya, yw = f(x, words)
+    np.testing.assert_array_equal(np.asarray(ya).view(np.uint32),
+                                  x.view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(yw),
+                                  np.broadcast_to(words, (3, 4, 5)))
 
 
 @pytest.mark.parametrize("k", [1, 31, 32, 33, 64, 100])

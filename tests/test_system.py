@@ -20,7 +20,6 @@ import numpy as np
 from repro.checkpoint import restore_checkpoint, save_checkpoint
 from repro.configs.base import ArchConfig, LayerSpec
 from repro.data.pipeline import DataConfig, SyntheticLM
-from repro.launch.mesh import compat_make_mesh, use_mesh
 from repro.models.transformer import init_lm
 from repro.optim import adamw
 from repro.serve.engine import ServeEngine
@@ -36,7 +35,8 @@ TINY = ArchConfig(
 
 
 def test_training_learns_markov_stream():
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     data = SyntheticLM(DataConfig(vocab_size=256, seq_len=64, global_batch=8,
                                   seed=3, markov_states=16))
     params, _ = init_lm(TINY, jax.random.PRNGKey(0))
@@ -44,7 +44,7 @@ def test_training_learns_markov_stream():
     state = opt.init(params)
     step_fn = jax.jit(make_allreduce_step(TINY, opt, has_encoder=False))
     losses = []
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         for s in range(80):
             tok, lab = data.global_arrays(s, mesh)
             params, state, m = step_fn(
@@ -93,9 +93,9 @@ def test_gossip_training_converges_multidevice():
         from repro.models.transformer import init_lm
         from repro.optim import adamw
         from repro.train.trainer import make_gossip_step, train_shardings
-        from repro.launch.mesh import compat_make_mesh, use_mesh
 
-        mesh = compat_make_mesh((8, 1), ("data", "model"))
+        mesh = jax.make_mesh((8, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = ArchConfig(name="t", n_layers=2, d_model=64, n_heads=4,
                          n_kv_heads=2, d_ff=128, vocab_size=256,
                          vocab_pad_multiple=128, dtype="float32",
@@ -109,7 +109,7 @@ def test_gossip_training_converges_multidevice():
         R = 8
         reps = [init_lm(cfg, k)[0] for k in jax.random.split(jax.random.PRNGKey(0), R)]
         params = jax.tree.map(lambda *xs: jnp.stack(xs), *reps)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = jax.tree.map(
                 lambda x, sp: jax.device_put(x, NamedSharding(mesh, sp)),
                 params, pspecs)
