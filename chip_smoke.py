@@ -55,24 +55,6 @@ CITY_N = 32768
 CITY_SLOTS = 400
 
 
-class _CompileClock:
-    """Seconds of XLA/Mosaic backend compilation (JAX's own monitoring
-    event), so each phase reports compile time apart from its wall time.
-    Tracing and lowering are left in the wall time: their events nest
-    (an outer jit's trace spans its inner jits'), compilations do not."""
-
-    def __init__(self):
-        import jax
-
-        self.total = 0.0
-
-        def listener(event, duration, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.total += duration
-
-        jax.monitoring.register_event_duration_secs_listener(listener)
-
-
 @contextlib.contextmanager
 def _kernel_calls(module, name: str, calls: list):
     """Record the ``interpret`` flag of every trace-time call of
@@ -247,15 +229,23 @@ def phase_learning():
     return ok, "holder test accuracy, first vs last tenth", detail, None
 
 
-def _run_phase(name, fn, clock, device, failures, *args):
-    c0, t0 = clock.total, time.perf_counter()
-    try:
-        ok, compared, detail, extra = fn(*args)
-    except Exception:  # noqa: BLE001 — report, then fail the run
-        traceback.print_exc()
-        ok, compared, detail, extra = False, "raised", {}, None
-    line = dict(phase=name, device=device,
-                compile_s=clock.total - c0,
+def _run_phase(name, fn, device, failures, *args):
+    """Run one phase as a span of its own: its compile seconds are the
+    backend compilations counted on the phase's spans (tracing and
+    lowering stay in the wall time: their events nest, compilations do
+    not)."""
+    from repro import spans
+
+    t0 = time.perf_counter()
+    with spans.span("fg.smoke", phase=name) as phase:
+        try:
+            ok, compared, detail, extra = fn(*args)
+        except Exception:  # noqa: BLE001 — report, then fail the run
+            traceback.print_exc()
+            ok, compared, detail, extra = False, "raised", {}, None
+    compile_s = sum(s.counters.get("compile", (0, 0.0))[1]
+                    for s in spans.tree(phase))
+    line = dict(phase=name, device=device, compile_s=compile_s,
                 wall_s=time.perf_counter() - t0,
                 compared=compared, ok=ok, **detail)
     print(json.dumps(line), flush=True)
@@ -264,13 +254,13 @@ def _run_phase(name, fn, clock, device, failures, *args):
     return extra
 
 
-def _four_chips(clock, device, failures):
+def _four_chips(device, failures):
     """The phase (a) study sharded over four chips vs one device."""
     import numpy as np
 
     outs = [
-        _run_phase(f"a_paper_study_{n}dev", phase_paper_study, clock,
-                   device, failures, n)
+        _run_phase(f"a_paper_study_{n}dev", phase_paper_study, device,
+                   failures, n)
         for n in (4, 1)
     ]
     if None in outs:
@@ -318,17 +308,16 @@ def main(argv=None) -> int:
     from repro.launch.cache import enable_compile_cache
 
     enable_compile_cache()
-    clock = _CompileClock()
     device = f"{dev.platform}:{dev.device_kind}"
     failures: list = []
     if args.four_chips:
-        _four_chips(clock, device, failures)
+        _four_chips(device, failures)
     else:
         for name, fn in (("a_paper_study", phase_paper_study),
                          ("b_kernels_vs_ref", phase_kernels),
                          ("c_city_cells", phase_city),
                          ("d_learning", phase_learning)):
-            _run_phase(name, fn, clock, device, failures)
+            _run_phase(name, fn, device, failures)
     if failures:
         print(f"chip_smoke: failed phases {failures}", file=sys.stderr)
         return 1

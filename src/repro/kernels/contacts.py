@@ -377,6 +377,7 @@ def pairwise_contacts(pos, in_rz, elig, prevw, r_tx2, access=None, *,
             jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="pairwise_contacts",
     )(x[:, None], y[:, None], rz[:, None], el[:, None],
       _bit_planes(x), _bit_planes(y), _bit_planes(rz), _bit_planes(el),
       prevw)
@@ -555,6 +556,7 @@ def cell_close_words(xc, yc, zc, idc, ncx: int, ncy: int, r_tx2, *,
                                lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((ncx, nwords, cap, nyp), jnp.int32),
         interpret=interpret,
+        name="cell_close_words",
     )(*inputs)
     # interior columns back to the oracle's (cell, slot, word) layout
     out = out[:, :, :, 1:ncy + 1].transpose(0, 3, 2, 1)

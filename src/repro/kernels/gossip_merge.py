@@ -89,6 +89,7 @@ def _merge_pallas(own, peer, w_own, success, *, interpret: bool):
         ),
         out_shape=jax.ShapeDtypeStruct((nb * BLK,), own.dtype),
         interpret=interpret,
+        name="gossip_merge",
     )(scalars, flat, pflat)
     return out[:n].reshape(shape)
 
@@ -148,6 +149,7 @@ def _rows_pallas(own, peer, w_own, success, *, interpret: bool):
         out_specs=pl.BlockSpec((BLK_ROWS, dp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * BLK_ROWS, dp), own.dtype),
         interpret=interpret,
+        name="gossip_merge_rows",
     )(w, s, own, peer)
     return out[:n, :d]
 
@@ -208,6 +210,7 @@ def _rows_scaled_pallas(own, peer, w_own, scale, success, *, interpret: bool):
         out_specs=pl.BlockSpec((BLK_ROWS, dp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * BLK_ROWS, dp), own.dtype),
         interpret=interpret,
+        name="gossip_merge_rows_scaled",
     )(w, c, s, own, peer)
     return out[:n, :d]
 

@@ -325,6 +325,10 @@ def _run(key, p_dyn: dict, cfg: SimConfig, M: int, trace: str = "full"):
     per-observation quantities (``obs_birth`` / ``obs_holders``) that only
     the o(τ) estimator consumes — reduced-output sweeps use it to skip the
     engine's one full ``inc`` unpack per sample.
+
+    Each stage of the slot step, and the per-sample outputs, run under a
+    ``jax.named_scope`` (``fg.mobility``, ``fg.contacts``, ...): op
+    metadata only, so a device trace can attribute time to a stage.
     """
     dt = cfg.dt
     t0, T_L, T_T, T_M = (p_dyn[k] for k in ("t0", "T_L", "T_T", "T_M"))
@@ -427,58 +431,63 @@ def _run(key, p_dyn: dict, cfg: SimConfig, M: int, trace: str = "full"):
         # *additional* split so the base split sequence above — and with it
         # every fault-free draw — stays bitwise untouched ----
         if faults_on:
-            key, k_duty, k_crash, k_link, k_abort = jax.random.split(key, 5)
-            availw, on = faults.duty_step(
-                k_duty, state.availw, p_off, p_on, cfg.n_nodes
-            )
-            access = on
+            with jax.named_scope("fg.faults"):
+                key, k_duty, k_crash, k_link, k_abort = jax.random.split(
+                    key, 5)
+                availw, on = faults.duty_step(
+                    k_duty, state.availw, p_off, p_on, cfg.n_nodes
+                )
+                access = on
         else:
             access = None
 
         # ---- mobility & zone membership ----
-        mob = model.step(k_mob1, k_mob2, state.mob, cfg)
-        member = zone_member(mob.pos, t_now)             # (N, K)
-        zonew = compute.pack_mask(member)[:, 0]          # (N,) uint32
-        in_rz = zonew != 0                               # union membership
+        with jax.named_scope("fg.mobility"):
+            mob = model.step(k_mob1, k_mob2, state.mob, cfg)
+            member = zone_member(mob.pos, t_now)             # (N, K)
+            zonew = compute.pack_mask(member)[:, 0]          # (N,) uint32
+            in_rz = zonew != 0                               # union membership
 
-        # ---- zone churn: leaving the *union* of zones drops everything;
-        # crossing directly from one zone into another transfers state ----
-        left, churned = zone_churn(
-            state.zone_prev, zonew, inc=state.inc, has_model=state.has_model,
-            tq_model=state.tq_model, mq_model=state.mq_model,
-            serving=state.serving, serv_left=state.serv_left,
-        )
-        inc, has_model = churned["inc"], churned["has_model"]
-        tq_model, mq_model = churned["tq_model"], churned["mq_model"]
-        serving, serv_left = churned["serving"], churned["serv_left"]
+            # ---- zone churn: leaving the *union* of zones drops everything;
+            # crossing directly from one zone into another transfers state ----
+            left, churned = zone_churn(
+                state.zone_prev, zonew, inc=state.inc,
+                has_model=state.has_model,
+                tq_model=state.tq_model, mq_model=state.mq_model,
+                serving=state.serving, serv_left=state.serv_left,
+            )
+            inc, has_model = churned["inc"], churned["has_model"]
+            tq_model, mq_model = churned["tq_model"], churned["mq_model"]
+            serving, serv_left = churned["serving"], churned["serv_left"]
 
-        # ---- crash-restart churn: drop packed protocol state through the
-        # same path zone churn uses; the node itself stays (and stays on) --
-        if faults_on:
-            crashed = jax.random.uniform(k_crash, (cfg.n_nodes,)) < p_crash
-            dropped = faults.drop_state(
-                crashed, inc=inc, has_model=has_model, tq_model=tq_model,
-                mq_model=mq_model, serving=serving, serv_left=serv_left,
-            )
-            inc, has_model = dropped["inc"], dropped["has_model"]
-            tq_model, mq_model = dropped["tq_model"], dropped["mq_model"]
-            serving, serv_left = dropped["serving"], dropped["serv_left"]
+            # ---- crash-restart churn: drop packed protocol state through
+            # the same path zone churn uses; the node itself stays (and
+            # stays on) --
+            if faults_on:
+                crashed = jax.random.uniform(k_crash, (cfg.n_nodes,)) < p_crash
+                dropped = faults.drop_state(
+                    crashed, inc=inc, has_model=has_model, tq_model=tq_model,
+                    mq_model=mq_model, serving=serving, serv_left=serv_left,
+                )
+                inc, has_model = dropped["inc"], dropped["has_model"]
+                tq_model, mq_model = dropped["tq_model"], dropped["mq_model"]
+                serving, serv_left = dropped["serving"], dropped["serv_left"]
 
-        # ---- learning churn: a node dropping its packed protocol state
-        # also resets its model replica to the shared init ----
-        if learn_on:
-            drop = (left | crashed) if faults_on else left
-            rr = learning.reset_replicas(
-                drop, state.theta, state.theta_cnt, state.theta_age,
-                task.theta0,
-                poisoned=state.poisoned if adv_on else None,
-                peer_fill=state.peer_fill if trimmed_on else None,
-            )
-            theta, theta_cnt, theta_age = (
-                rr["theta"], rr["theta_cnt"], rr["theta_age"]
-            )
-            poisoned = rr.get("poisoned")
-            peer_fill = rr.get("peer_fill")
+            # ---- learning churn: a node dropping its packed protocol state
+            # also resets its model replica to the shared init ----
+            if learn_on:
+                drop = (left | crashed) if faults_on else left
+                rr = learning.reset_replicas(
+                    drop, state.theta, state.theta_cnt, state.theta_age,
+                    task.theta0,
+                    poisoned=state.poisoned if adv_on else None,
+                    peer_fill=state.peer_fill if trimmed_on else None,
+                )
+                theta, theta_cnt, theta_age = (
+                    rr["theta"], rr["theta_cnt"], rr["theta_age"]
+                )
+                poisoned = rr.get("poisoned")
+                peer_fill = rr.get("peer_fill")
 
         # ---- contact dynamics ----
         # Dense backend: the O(N²) pairwise sweep in two stages — the
@@ -492,186 +501,201 @@ def _run(key, p_dyn: dict, cfg: SimConfig, M: int, trace: str = "full"):
         # grid (also shared per seed — they too depend only on positions
         # and zones) replace the matrix; the partner-proximity bit is
         # the O(N) pair recompute, bitwise the same criterion.
-        if use_cells:
-            # access is seed-only state (its key chain never touches the
-            # scenario-dependent p_dyn), so the neighbor stage stays a
-            # shared per-seed stage under the barrier
-            nbr, ovf = cells.neighbor_lists(
-                mob.pos, zonew, grid, r_tx2, access
-            )
-            nbr = compute.shared_barrier(nbr)
-            still_close = contacts.pair_still_close(
-                mob.pos, zonew, state.partner, r_tx2, access
-            )
-        else:
-            closew_shared, d2ctx = contacts.pairwise_close(
-                mob.pos, member, r_tx2, access
-            )
-            if closew_shared is None:
+        with jax.named_scope("fg.contacts"):
+            if use_cells:
+                # access is seed-only state (its key chain never touches the
+                # scenario-dependent p_dyn), so the neighbor stage stays a
+                # shared per-seed stage under the barrier
+                nbr, ovf = cells.neighbor_lists(
+                    mob.pos, zonew, grid, r_tx2, access
+                )
+                nbr = compute.shared_barrier(nbr)
                 still_close = contacts.pair_still_close(
                     mob.pos, zonew, state.partner, r_tx2, access
                 )
             else:
-                still_close = contacts.partner_close_bit(
-                    closew_shared, state.partner
+                closew_shared, d2ctx = contacts.pairwise_close(
+                    mob.pos, member, r_tx2, access
                 )
-        # mid-transfer link failure breaks the exchange exactly like
-        # moving out of range (completed transfers are still delivered)
-        if faults_on:
-            lfail = faults.link_fail(k_link, p_link, state.partner)
-            still_close = still_close & ~lfail
-        elapsed, done, broke, ending, eff_time, pidx = contacts.advance_exchanges(
-            partner=state.partner, exch_elapsed=state.exch_elapsed,
-            exch_total=state.exch_total, still_close=still_close, dt=dt,
-        )
-        delivered, sender_words = contacts.compute_deliveries(
-            order_seed=state.order_seed, snap_has=state.snap_has,
-            snap=state.snap, pidx=pidx, eff_time=eff_time, ending=ending,
-            t0=t0, T_L=T_L,
-        )
-        if faults_on:
-            # free-riders receive but never serve
-            delivered = faults.gate_deliveries(delivered, pidx, is_fr)
+                if closew_shared is None:
+                    still_close = contacts.pair_still_close(
+                        mob.pos, zonew, state.partner, r_tx2, access
+                    )
+                else:
+                    still_close = contacts.partner_close_bit(
+                        closew_shared, state.partner
+                    )
+            # mid-transfer link failure breaks the exchange exactly like
+            # moving out of range (completed transfers are still delivered)
+            if faults_on:
+                lfail = faults.link_fail(k_link, p_link, state.partner)
+                still_close = still_close & ~lfail
+            elapsed, done, broke, ending, eff_time, pidx = (
+                contacts.advance_exchanges(
+                    partner=state.partner, exch_elapsed=state.exch_elapsed,
+                    exch_total=state.exch_total, still_close=still_close,
+                    dt=dt,
+                )
+            )
+        with jax.named_scope("fg.deliveries"):
+            delivered, sender_words = contacts.compute_deliveries(
+                order_seed=state.order_seed, snap_has=state.snap_has,
+                snap=state.snap, pidx=pidx, eff_time=eff_time, ending=ending,
+                t0=t0, T_L=T_L,
+            )
+            if faults_on:
+                # free-riders receive but never serve
+                delivered = faults.gate_deliveries(delivered, pidx, is_fr)
 
         # ---- learning merge: a delivery of the learned model's instance
         # merges the sender's connection-time parameter snapshot into the
         # receiver (the paper's weighted-coefficient average, fused kernel)
         if learn_on:
-            md = learning.merge_deliveries(
-                lc, delivered[:, learning.LEARN_MODEL], pidx,
-                theta, theta_cnt, theta_age,
-                state.theta_snap, state.snap_cnt, state.snap_age, tau_l,
-                merge_stats=state.merge_stats,
-                poisoned=poisoned,
-                snap_poison=state.snap_poison if adv_on else None,
-                peer_buf=state.peer_buf if trimmed_on else None,
-                peer_fill=peer_fill,
-            )
-            theta, theta_cnt, theta_age = (
-                md["theta"], md["theta_cnt"], md["theta_age"]
-            )
-            merge_stats = md["merge_stats"]
-            poisoned = md.get("poisoned", poisoned)
-            peer_buf = md.get("peer_buf")
-            peer_fill = md.get("peer_fill", peer_fill)
+            with jax.named_scope("fg.learn.merge"):
+                md = learning.merge_deliveries(
+                    lc, delivered[:, learning.LEARN_MODEL], pidx,
+                    theta, theta_cnt, theta_age,
+                    state.theta_snap, state.snap_cnt, state.snap_age, tau_l,
+                    merge_stats=state.merge_stats,
+                    poisoned=poisoned,
+                    snap_poison=state.snap_poison if adv_on else None,
+                    peer_buf=state.peer_buf if trimmed_on else None,
+                    peer_fill=peer_fill,
+                )
+                theta, theta_cnt, theta_age = (
+                    md["theta"], md["theta_cnt"], md["theta_age"]
+                )
+                merge_stats = md["merge_stats"]
+                poisoned = md.get("poisoned", poisoned)
+                peer_buf = md.get("peer_buf")
+                peer_fill = md.get("peer_fill", peer_fill)
 
         # enqueue merge jobs for delivered instances that add information
         # (merge only when the received training set is not a subset of the
         # local one — Y of Definition 4). A received instance is NOT
         # used/propagated until merged (paper §III-C) — has_model flips only
         # at merge completion.
-        adds = delivered & compute.packed_any(sender_words & ~inc)
-        mq_model, mq_mask = compute.enqueue_ascending(
-            mq_model, adds, (state.mq_mask, sender_words)
-        )
+        with jax.named_scope("fg.deliveries"):
+            adds = delivered & compute.packed_any(sender_words & ~inc)
+            mq_model, mq_mask = compute.enqueue_ascending(
+                mq_model, adds, (state.mq_mask, sender_words)
+            )
 
         # ---- release ending pairs, form new connections ----
-        partner = jnp.where(ending, -1, state.partner)
-        elig = (partner < 0) & in_rz
-        if faults_on:
-            # redundant with the access-folded close sets, but keeps the
-            # eligibility invariant explicit on every matching path
-            elig = elig & on
-        if use_cells:
-            best, has = cells.candidate_best(
-                mob.pos, nbr, state.prev_close, elig
+        with jax.named_scope("fg.matching"):
+            partner = jnp.where(ending, -1, state.partner)
+            elig = (partner < 0) & in_rz
+            if faults_on:
+                # redundant with the access-folded close sets, but keeps the
+                # eligibility invariant explicit on every matching path
+                elig = elig & on
+            if use_cells:
+                best, has = cells.candidate_best(
+                    mob.pos, nbr, state.prev_close, elig
+                )
+                match = contacts.mutualize(best, has)
+                closew = nbr        # the cells-path prev_close carry
+            else:
+                closew, match = contacts.match_candidates(
+                    d2ctx, state.prev_close, elig
+                )
+            if faults_on:
+                # per-contact connection-setup abort (symmetric coin)
+                match, aborted = faults.abort_matches(k_abort, fc.p_abort,
+                                                      match)
+            conn = contacts.form_connections(
+                partner=partner, match=match, has_model=has_model, inc=inc,
+                snap=state.snap, snap_has=state.snap_has,
+                exch_elapsed=elapsed, exch_total=state.exch_total,
+                order_seed=state.order_seed, slot_idx=slot_idx, t0=t0, T_L=T_L,
             )
-            match = contacts.mutualize(best, has)
-            closew = nbr        # the cells-path prev_close carry
-        else:
-            closew, match = contacts.match_candidates(
-                d2ctx, state.prev_close, elig
-            )
-        if faults_on:
-            # per-contact connection-setup abort (symmetric coin)
-            match, aborted = faults.abort_matches(k_abort, fc.p_abort, match)
-        conn = contacts.form_connections(
-            partner=partner, match=match, has_model=has_model, inc=inc,
-            snap=state.snap, snap_has=state.snap_has,
-            exch_elapsed=elapsed, exch_total=state.exch_total,
-            order_seed=state.order_seed, slot_idx=slot_idx, t0=t0, T_L=T_L,
-        )
         # ---- learning snapshot: parameters are frozen alongside the
         # protocol's snap words when a connection forms; the Byzantine
         # attack then transforms the snapshot an adversarial node just
         # took — the serve side — leaving its live replica untouched ----
         if learn_on:
-            newly = match >= 0
-            snap = learning.snapshot_params(
-                newly, theta, theta_cnt, theta_age,
-                state.theta_snap, state.snap_cnt, state.snap_age,
-                poisoned=poisoned,
-                snap_poison=state.snap_poison if adv_on else None,
-            )
-            if adv_on:
-                theta_snap, snap_cnt, snap_age, snap_poison = snap
-                theta_snap, snap_cnt, snap_age, snap_poison = (
-                    learning.poison_snapshots(
-                        adv, task, slot_idx, newly,
-                        theta_snap, snap_cnt, snap_age, snap_poison,
-                    )
+            with jax.named_scope("fg.learn.snapshot"):
+                newly = match >= 0
+                snap = learning.snapshot_params(
+                    newly, theta, theta_cnt, theta_age,
+                    state.theta_snap, state.snap_cnt, state.snap_age,
+                    poisoned=poisoned,
+                    snap_poison=state.snap_poison if adv_on else None,
                 )
-            else:
-                theta_snap, snap_cnt, snap_age = snap
+                if adv_on:
+                    theta_snap, snap_cnt, snap_age, snap_poison = snap
+                    theta_snap, snap_cnt, snap_age, snap_poison = (
+                        learning.poison_snapshots(
+                            adv, task, slot_idx, newly,
+                            theta_snap, snap_cnt, snap_age, snap_poison,
+                        )
+                    )
+                else:
+                    theta_snap, snap_cnt, snap_age = snap
 
         # ---- observation generation & training enqueue ----
-        obs_birth, obs_head, inc, want_train, slot_payload = (
-            observations.generate_observations(
-                k_obs=k_obs, k_who=k_who, obs_birth=state.obs_birth,
-                obs_head=state.obs_head, inc=inc,
-                in_rz=(in_rz & on) if faults_on else in_rz,
-                lam=lam, Lam=Lam, dt=dt, t_now=t_now,
+        with jax.named_scope("fg.observations"):
+            obs_birth, obs_head, inc, want_train, slot_payload = (
+                observations.generate_observations(
+                    k_obs=k_obs, k_who=k_who, obs_birth=state.obs_birth,
+                    obs_head=state.obs_head, inc=inc,
+                    in_rz=(in_rz & on) if faults_on else in_rz,
+                    lam=lam, Lam=Lam, dt=dt, t_now=t_now,
+                )
             )
-        )
-        tq_model, tq_slot = compute.enqueue_ascending(
-            tq_model, want_train, (state.tq_slot, slot_payload)
-        )
+            tq_model, tq_slot = compute.enqueue_ascending(
+                tq_model, want_train, (state.tq_slot, slot_payload)
+            )
 
         # ---- compute server: finish jobs, then pick next (merge priority) --
         # an off node's compute is dormant: its service timer freezes
         # (per-node dt = 0) and it starts no new job (can_serve below)
-        serv_left, fin_merge, fin_train = compute.advance_timers(
-            serving, serv_left,
-            jnp.where(on, dt, 0.0) if faults_on else dt,
-        )
-        inc, has_model = observations.apply_completions(
-            fin_merge=fin_merge, fin_train=fin_train,
-            serv_model=state.serv_model, serv_mask=state.serv_mask,
-            serv_slot=state.serv_slot, inc=inc, has_model=has_model,
-            obs_birth=obs_birth,
-        )
-        serving = jnp.where(fin_merge | fin_train, -1, serving)
+        with jax.named_scope("fg.compute"):
+            serv_left, fin_merge, fin_train = compute.advance_timers(
+                serving, serv_left,
+                jnp.where(on, dt, 0.0) if faults_on else dt,
+            )
+            inc, has_model = observations.apply_completions(
+                fin_merge=fin_merge, fin_train=fin_train,
+                serv_model=state.serv_model, serv_mask=state.serv_mask,
+                serv_slot=state.serv_slot, inc=inc, has_model=has_model,
+                obs_birth=obs_birth,
+            )
+            serving = jnp.where(fin_merge | fin_train, -1, serving)
         # ---- learning train step: a finished training job on the learned
         # model whose observation is still in the ring (the same freshness
         # gate apply_completions uses) takes one local SGD step ----
         if learn_on:
-            did_train = (
-                fin_train
-                & (state.serv_model == learning.LEARN_MODEL)
-                & (obs_birth[learning.LEARN_MODEL, state.serv_slot]
-                   > -jnp.inf)
+            with jax.named_scope("fg.learn.train"):
+                did_train = (
+                    fin_train
+                    & (state.serv_model == learning.LEARN_MODEL)
+                    & (obs_birth[learning.LEARN_MODEL, state.serv_slot]
+                       > -jnp.inf)
+                )
+                theta, theta_cnt, theta_age = learning.train_completions(
+                    lc, task, slot_idx, did_train, theta, theta_cnt, theta_age,
+                    dt,
+                )
+        with jax.named_scope("fg.compute"):
+            served = compute.pick_next_jobs(
+                serving=serving, serv_left=serv_left,
+                serv_model=state.serv_model, serv_mask=state.serv_mask,
+                serv_slot=state.serv_slot, mq_model=mq_model, mq_mask=mq_mask,
+                tq_model=tq_model, tq_slot=tq_slot, T_M=T_M, T_T=T_T,
+                can_serve=on if faults_on else None,
             )
-            theta, theta_cnt, theta_age = learning.train_completions(
-                lc, task, slot_idx, did_train, theta, theta_cnt, theta_age,
-                dt,
-            )
-        served = compute.pick_next_jobs(
-            serving=serving, serv_left=serv_left,
-            serv_model=state.serv_model, serv_mask=state.serv_mask,
-            serv_slot=state.serv_slot, mq_model=mq_model, mq_mask=mq_mask,
-            tq_model=tq_model, tq_slot=tq_slot, T_M=T_M, T_T=T_T,
-            can_serve=on if faults_on else None,
-        )
 
         fault_kw = {}
         if faults_on:
-            events = jnp.stack([
-                jnp.sum(aborted),
-                jnp.sum((state.partner >= 0) & lfail),
-                jnp.sum(crashed),
-            ]).astype(jnp.int32)
-            fault_kw = dict(availw=availw,
-                            fault_events=state.fault_events + events)
+            with jax.named_scope("fg.faults"):
+                events = jnp.stack([
+                    jnp.sum(aborted),
+                    jnp.sum((state.partner >= 0) & lfail),
+                    jnp.sum(crashed),
+                ]).astype(jnp.int32)
+                fault_kw = dict(availw=availw,
+                                fault_events=state.fault_events + events)
         learn_kw = {}
         if learn_on:
             learn_kw = dict(
@@ -700,32 +724,33 @@ def _run(key, p_dyn: dict, cfg: SimConfig, M: int, trace: str = "full"):
         slots = chunk_idx * cfg.sample_every + jnp.arange(cfg.sample_every)
         (state, key), _ = jax.lax.scan(step, carry, slots)
         t_now = slots[-1].astype(jnp.float32) * dt
-        out = observations.slot_outputs(
-            inc=state.inc, has_model=state.has_model,
-            obs_birth=state.obs_birth, in_rz=state.zone_prev != 0,
-            member=compute.unpack_mask(state.zone_prev[:, None], kz),
-            partner=state.partner, t_now=t_now, tau_l=tau_l,
-            with_obs_trace=(trace == "full"),
-        )
-        if use_cells:
-            out["nbr_overflow"] = state.nbr_overflow
-        if faults_on:
-            out.update(faults.fault_outputs(
-                on=compute.unpack_mask(
-                    state.availw[None, :], cfg.n_nodes
-                )[0],
-                in_rz=state.zone_prev != 0, has_model=state.has_model,
-                cls1h=cls1h, n_per_class=n_per_class,
-                fault_events=state.fault_events,
-            ))
-        if learn_on:
-            out.update(learning.learn_outputs(
-                lc, task, state.theta, state.theta_cnt,
-                has_model=state.has_model, in_rz=state.zone_prev != 0,
-                merge_stats=state.merge_stats,
-                poisoned=state.poisoned if adv_on else None,
-                cls1h=cls1h_adv if adv_on else None,
-            ))
+        with jax.named_scope("fg.outputs"):
+            out = observations.slot_outputs(
+                inc=state.inc, has_model=state.has_model,
+                obs_birth=state.obs_birth, in_rz=state.zone_prev != 0,
+                member=compute.unpack_mask(state.zone_prev[:, None], kz),
+                partner=state.partner, t_now=t_now, tau_l=tau_l,
+                with_obs_trace=(trace == "full"),
+            )
+            if use_cells:
+                out["nbr_overflow"] = state.nbr_overflow
+            if faults_on:
+                out.update(faults.fault_outputs(
+                    on=compute.unpack_mask(
+                        state.availw[None, :], cfg.n_nodes
+                    )[0],
+                    in_rz=state.zone_prev != 0, has_model=state.has_model,
+                    cls1h=cls1h, n_per_class=n_per_class,
+                    fault_events=state.fault_events,
+                ))
+            if learn_on:
+                out.update(learning.learn_outputs(
+                    lc, task, state.theta, state.theta_cnt,
+                    has_model=state.has_model, in_rz=state.zone_prev != 0,
+                    merge_stats=state.merge_stats,
+                    poisoned=state.poisoned if adv_on else None,
+                    cls1h=cls1h_adv if adv_on else None,
+                ))
         return (state, key), out
 
     mob0, key = model.init(key, cfg)

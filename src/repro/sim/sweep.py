@@ -51,6 +51,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import os
+import time
 import warnings
 from functools import lru_cache
 from typing import Sequence
@@ -59,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.meanfield import FGParams
 from repro.sharding.logical import SWEEP_RULES, spec_for
 from repro.sim.engine import (
@@ -297,7 +299,8 @@ def _worker_fn(cfg: SimConfig, M: int, reduce: str, s0: int, qs: tuple,
         outs = jax.vmap(over_seeds, in_axes=(None, 0))(keys, p_chunk)
         if reduce == "trace":
             return outs
-        return _reduce_outs(outs, reduce, s0, qs, tau, t_const)
+        with jax.named_scope("fg.reduce"):
+            return _reduce_outs(outs, reduce, s0, qs, tau, t_const)
 
     return worker
 
@@ -733,6 +736,11 @@ def run(
                   cache makes a fresh worker load the chunk program
                   instead of recompiling.
 
+    An in-process call records its host phases as ``repro.spans``
+    spans: an ``fg.sweep`` root with ``fg.sweep.prepare``, ``.keys``,
+    ``.dispatch`` and ``.pull`` per chunk, ``.checkpoint`` and
+    ``.finalize``.
+
     Returns:
       ``BatchSimOutputs`` for ``reduce="trace"`` — with the extra
       attributes ``plan``/``devices_used``/``host_bytes``/``coverage``
@@ -749,11 +757,24 @@ def run(
             queue_dir=queue_dir, xla_cache_dir=xla_cache_dir,
         )
 
-    setup = _prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size,
-                     quantiles, tau_grid, n_devices)
-    plan = setup.plan
-    keys = setup.keys()
+    with spans.span("fg.sweep", reduce=reduce, slots=cfg.n_slots) as root:
+        with spans.span("fg.sweep.prepare"):
+            setup = _prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size,
+                             quantiles, tau_grid, n_devices)
+        plan = setup.plan
+        root.attrs.update(runs=plan.n_scenarios * plan.n_seeds,
+                          chunks=plan.n_chunks)
+        with spans.span("fg.sweep.keys"):
+            keys = setup.keys()
+        return _run_chunks(setup, keys, seeds, checkpoint_dir, resume,
+                           retry_policy)
 
+
+def _run_chunks(setup: _SweepSetup, keys, seeds, checkpoint_dir, resume,
+                retry_policy):
+    """Dispatch, pull (and, with ``checkpoint_dir``, save) the chunks of
+    one in-process sweep, then assemble its result."""
+    plan = setup.plan
     worker_cell: list = []
 
     def dispatch_chunk(c):
@@ -786,16 +807,20 @@ def run(
         host_chunks: list[dict] = []
         pending = None
         for c in range(plan.n_chunks):
-            out = dispatch_chunk(c)
-            note_devices(out)
+            with spans.span("fg.sweep.dispatch", chunk=c):
+                out = dispatch_chunk(c)
+                note_devices(out)
             if pending is not None:
                 # double buffer: materialize chunk c-1 while chunk c runs
-                host_chunks.append(
-                    jax.tree_util.tree_map(np.asarray, pending)
-                )
+                with spans.span("fg.sweep.pull", chunk=c - 1):
+                    host_chunks.append(
+                        jax.tree_util.tree_map(np.asarray, pending)
+                    )
             pending = out
-        host_chunks.append(jax.tree_util.tree_map(np.asarray, pending))
-        return _finalize(setup, host_chunks, devices_used=devices_used)
+        with spans.span("fg.sweep.pull", chunk=plan.n_chunks - 1):
+            host_chunks.append(jax.tree_util.tree_map(np.asarray, pending))
+        with spans.span("fg.sweep.finalize"):
+            return _finalize(setup, host_chunks, devices_used=devices_used)
 
     from repro.checkpoint.ckpt import save_checkpoint
     from repro.sim.dispatch import RetryPolicy
@@ -803,12 +828,13 @@ def run(
     policy = retry_policy if retry_policy is not None else RetryPolicy()
     fp = _setup_fingerprint(setup, seeds)
     expected = setup.expected_shapes()
-    done = (_load_chunks(checkpoint_dir, fp, plan.n_chunks,
-                         expected=expected)
-            if resume else {})
+    done = {}
+    if resume:
+        with spans.span("fg.sweep.checkpoint", resume=True):
+            done = _load_chunks(checkpoint_dir, fp, plan.n_chunks,
+                                expected=expected)
     telemetry: dict = {"chunks": {}}
     by_idx: dict[int, dict] = {}
-    import time as _time
 
     for c in range(plan.n_chunks):
         if c in done:
@@ -816,15 +842,21 @@ def run(
             telemetry["chunks"][c] = {"attempts": 0, "resumed": True}
             continue
         hc = None
-        t_claim = _time.monotonic()
         attempt = 0
+        tried: list[spans.Span] = []     # the chunk's dispatch/pull spans
         for attempt in range(policy.max_attempts):
             # only Exception is retried — a kill signal
             # (KeyboardInterrupt/SystemExit) propagates, which is the
             # preemption this path checkpoints against
             try:
-                out = dispatch_chunk(c)
-                hc = jax.tree_util.tree_map(np.asarray, out)
+                with spans.span("fg.sweep.dispatch", chunk=c,
+                                attempt=attempt) as s:
+                    tried.append(s)
+                    out = dispatch_chunk(c)
+                with spans.span("fg.sweep.pull", chunk=c,
+                                attempt=attempt) as s:
+                    tried.append(s)
+                    hc = jax.tree_util.tree_map(np.asarray, out)
                 # validate the (possibly retried) output against the
                 # worker's contract before anything is checkpointed — a
                 # retry that returned drifted shapes must not poison the
@@ -844,8 +876,10 @@ def run(
                 if attempt + 1 < policy.max_attempts:
                     delay = policy.backoff(attempt + 1, key=f"{fp}:{c}")
                     if delay > 0:
-                        _time.sleep(delay)
-        latency = _time.monotonic() - t_claim
+                        time.sleep(delay)
+        # from the first attempt's dispatch to the end of the last
+        # attempt's span, backoff included
+        latency = (tried[-1].t1_ns - tried[0].t0_ns) * 1e-9
         if hc is None:
             failed.append(c)
             by_idx[c] = _fill_chunk(expected)
@@ -853,16 +887,18 @@ def run(
                 "attempts": policy.max_attempts, "latency_s": latency,
             }
             continue
-        save_checkpoint(
-            checkpoint_dir, c, dict(hc, fingerprint=_fp_array(fp)),
-            meta={"chunk": c, "attempt": attempt,
-                  "fingerprint": fp, "schema": "sweep-chunk-v1"},
-            integrity=True, atomic=True,
-        )
+        with spans.span("fg.sweep.checkpoint", chunk=c):
+            save_checkpoint(
+                checkpoint_dir, c, dict(hc, fingerprint=_fp_array(fp)),
+                meta={"chunk": c, "attempt": attempt,
+                      "fingerprint": fp, "schema": "sweep-chunk-v1"},
+                integrity=True, atomic=True,
+            )
         by_idx[c] = hc
         telemetry["chunks"][c] = {
             "attempts": attempt + 1, "latency_s": latency,
         }
     host_chunks = [by_idx[c] for c in range(plan.n_chunks)]
-    return _finalize(setup, host_chunks, devices_used=devices_used,
-                     failed=failed, telemetry=telemetry)
+    with spans.span("fg.sweep.finalize"):
+        return _finalize(setup, host_chunks, devices_used=devices_used,
+                         failed=failed, telemetry=telemetry)

@@ -8,8 +8,10 @@ imported — and the persistent compilation cache is off around the
 compiles (an entry compiled for a described chip cannot be read back).
 """
 
+import ast
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +20,22 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import gossip_merge as gm
 from repro.kernels.contacts import cell_close_words, pairwise_contacts
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CUSTOM_CALL = re.compile(
+    r"^\s*(?:ROOT )?%([A-Za-z0-9_.\-]+?)(?:\.\d+)? = .*custom-call\(.*"
+    r'custom_call_target="tpu_custom_call"')
+
+
+def _reader_names(metric: str) -> tuple:
+    """The kernel names the benchmark's reader ``metric`` accepts (its
+    ``NAMES``), read from the reader's file."""
+    with open(os.path.join(ROOT, "bench", "layers", metric + ".py")) as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and node.targets[0].id == "NAMES":
+            return ast.literal_eval(node.value)
+    raise LookupError(f"{metric} has no NAMES")
 
 
 @pytest.fixture(scope="module")
@@ -44,9 +62,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiles_to_kernel(fn, *shapes):
+def _kernel_names(text: str) -> set:
+    """Base names of the compiled program's kernel instructions: what a
+    device trace names the kernel's events after."""
+    return {m.group(1) for m in map(_CUSTOM_CALL.match, text.splitlines())
+            if m}
+
+
+def _compiles_to_kernel(fn, *shapes) -> set:
     text = jax.jit(fn).lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in text
+    return _kernel_names(text)
 
 
 @pytest.mark.parametrize("n", [200, 4096])
@@ -54,11 +80,14 @@ def test_pairwise_contacts_compiles(one_chip, n):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    _compiles_to_kernel(
+    names = _compiles_to_kernel(
         lambda pos, rz, el, pw: pairwise_contacts(pos, rz, el, pw, 25.0),
         s((n, 2), jnp.float32), s((n,), jnp.bool_), s((n,), jnp.bool_),
         s((n, (n + 31) // 32), jnp.uint32),
     )
+    assert names == {"pairwise_contacts"}
+    for metric in ("pairwise_contacts_roofline", "pairwise_contacts_share"):
+        assert names <= set(_reader_names(metric)), metric
 
 
 def test_cell_close_words_compiles_on_a_city_grid_slice(one_chip):
@@ -70,10 +99,11 @@ def test_cell_close_words_compiles_on_a_city_grid_slice(one_chip):
     def s(dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    _compiles_to_kernel(
+    names = _compiles_to_kernel(
         lambda x, y, z, i: cell_close_words(x, y, z, i, ncx, ncy, 25.0),
         s(jnp.float32), s(jnp.float32), s(jnp.uint32), s(jnp.int32),
     )
+    assert names == {"cell_close_words"}
 
 
 @pytest.mark.parametrize("scaled", [False, True])
@@ -87,15 +117,32 @@ def test_gossip_merge_rows_compile(one_chip, scaled):
 
     rows, vec = s((n, d)), s((n,))
     if scaled:
-        _compiles_to_kernel(
+        names = _compiles_to_kernel(
             lambda a, b, w, c, ok: gm._rows_scaled_pallas(
                 a, b, w, c, ok, interpret=False),
             rows, rows, vec, vec, s((n,), jnp.bool_))
+        assert names == {"gossip_merge_rows_scaled"}
     else:
-        _compiles_to_kernel(
+        names = _compiles_to_kernel(
             lambda a, b, w, ok: gm._rows_pallas(a, b, w, ok,
                                                 interpret=False),
             rows, rows, vec, s((n,), jnp.bool_))
+        assert names == {"gossip_merge_rows"}
+        assert names <= set(_reader_names("gossip_merge_rows_roofline"))
+
+
+def test_gossip_merge_compiles(one_chip):
+    from repro.configs.fg_learn import logreg_task
+
+    d = logreg_task().param_dim
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    names = _compiles_to_kernel(
+        lambda a, b, w, ok: gm._merge_pallas(a, b, w, ok, interpret=False),
+        s((d,)), s((d,)), s(()), s(()))
+    assert names == {"gossip_merge"}
 
 
 def test_sharded_sweep_program_compiles_for_four_chips(topo, monkeypatch):
@@ -126,7 +173,7 @@ def test_sharded_sweep_program_compiles_for_four_chips(topo, monkeypatch):
     finally:
         sweep._chunk_worker.cache_clear()  # drop the described-chip mesh
     assert setup.plan.mesh_shape == (4, 1)
-    assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"pairwise_contacts"}
 
 
 def test_city_grid_slice_is_the_real_grid():
