@@ -1,5 +1,6 @@
-"""Vectorized compute-queue operations (merge/train priority queues) and
-the bit-packed mask word layout shared by the whole engine.
+"""Vectorized compute-queue operations (merge/train priority queues), the
+bit-packed mask word layout shared by the whole engine, and
+:func:`take_nodes`, the engine's reads of a per-node table by node index.
 
 The legacy simulator enqueued jobs with a Python loop over the model count
 ``M`` (one masked scatter per model), so the traced program — and hence
@@ -52,12 +53,76 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro.sim.cells import AUTO_CELLS_MIN_N
 
 __all__ = [
     "enqueue_ascending", "pick_next_jobs", "advance_timers",
     "pack_mask", "unpack_mask", "packed_onehot", "packed_any",
-    "packed_popcount", "shared_barrier",
+    "packed_popcount", "shared_barrier", "take_nodes", "take_path",
+    "TAKE_SELECT_MAX",
 ]
+
+#: Longest table :func:`take_nodes` reads by a one-hot select; longer
+#: tables take the indexed gather. The dense contact range: there the
+#: slot already does O(N²) work, while the cells backend (city scale)
+#: must stay O(N) per read.
+TAKE_SELECT_MAX = AUTO_CELLS_MIN_N
+
+
+def take_path(n: int) -> str:
+    """How :func:`take_nodes` reads a table of ``n`` rows: ``"onehot"``
+    (the select) or ``"index"`` (the gather)."""
+    return "onehot" if n <= TAKE_SELECT_MAX else "index"
+
+
+def take_nodes(table, idx: jnp.ndarray):
+    """``table[idx]`` for a per-node (or per-slot) table and a vector of
+    row indices in ``[-L, L)``, ``L = table.shape[0]`` (a negative index
+    wraps, as in ``table[idx]``). ``table`` may be a tuple of tables with
+    the same rows, read through one select: the result is then the tuple
+    of their reads.
+
+    Up to :data:`TAKE_SELECT_MAX` rows the read is a one-hot select: an
+    ``(L, N)`` compare of the row ids against ``idx`` (table rows on
+    sublanes, readers on lanes), the matching row kept as uint32 bits and
+    OR-reduced over the rows. An indexed gather on the TPU is a serial
+    loop of ~10 ns per element, while the select is a few dense vector
+    passes. Bits move unchanged, so the result is bitwise ``table[idx]``
+    for every value (NaN, ``-0.0``, ``±inf``); a float sum or an MXU
+    product would not be. Tables of 4-byte dtypes or bool; longer tables
+    trace exactly ``table[idx]``."""
+    tables = table if isinstance(table, tuple) else (table,)
+    if take_path(tables[0].shape[0]) == "index":
+        got = tuple(t[idx] for t in tables)
+    else:
+        with jax.named_scope("fg.take"):
+            got = _take_select(tables, idx)
+    return got if isinstance(table, tuple) else got[0]
+
+
+def _take_select(tables, idx):
+    n = tables[0].shape[0]
+    cols = [
+        (t.astype(jnp.uint32) if t.dtype == jnp.bool_
+         else jax.lax.bitcast_convert_type(t, jnp.uint32)).reshape(n, -1)
+        for t in tables
+    ]
+    bits = jnp.concatenate(cols, axis=1)
+    idx = jnp.where(idx < 0, idx + n, idx)
+    onehot = jnp.arange(n, dtype=idx.dtype)[:, None] == idx[None, :]
+    picked = jax.lax.reduce(
+        jnp.where(onehot, bits.T[:, :, None], jnp.uint32(0)), np.uint32(0),
+        jax.lax.bitwise_or, (1,),
+    ).T                                                     # (N, W)
+    out, w0 = [], 0
+    for t, c in zip(tables, cols):
+        word = picked[:, w0:w0 + c.shape[1]].reshape(idx.shape + t.shape[1:])
+        w0 += c.shape[1]
+        out.append(word != 0 if t.dtype == jnp.bool_
+                   else jax.lax.bitcast_convert_type(word, t.dtype))
+    return tuple(out)
 
 
 def shared_barrier(x):

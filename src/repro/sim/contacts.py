@@ -14,8 +14,11 @@ runs as two stages — :func:`pairwise_close` (positions/RZ only: the
 shared per seed in sweep batches) and :func:`match_candidates` (the
 per-run best new-contact candidate + mutual-best matching). On TPU the
 fused Pallas kernel runs the whole sweep in the second stage instead.
-Only O(N) work — the partner-proximity bit and the mutual-best check —
-remains here. Exchange snapshots (``snap``) travel bit-packed as well.
+Only per-node work — the partner-proximity bit and the mutual-best
+check — remains here; its reads of a partner's row go through
+``repro.sim.compute.take_nodes`` (a one-hot select in the dense range, a
+gather above it). Exchange snapshots (``snap``) travel bit-packed as
+well.
 
 This module is the *dense* contact backend. For large N the engine
 swaps these stages for the O(N) cell-list backend (``repro.sim.cells``,
@@ -31,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.kernels.contacts import (apply_access, candidate_best_ref,
                                     pairwise_close_ref)
+from repro.sim.compute import take_nodes
 
 __all__ = [
     "mutualize",
@@ -53,7 +57,8 @@ def mutualize(best: jnp.ndarray, has: jnp.ndarray) -> jnp.ndarray:
     the -1 no-candidate sentinel (it indexes the last row, which the
     ``has`` gate then discards)."""
     n = best.shape[0]
-    mutual = (best[best] == jnp.arange(n)) & has & has[best]
+    best_of_best, has_best = take_nodes((best, has), best)
+    mutual = (best_of_best == jnp.arange(n)) & has & has_best
     return jnp.where(mutual, best, -1)
 
 
@@ -92,7 +97,7 @@ def close_matrix(pos: jnp.ndarray, in_rz: jnp.ndarray, r_tx) -> jnp.ndarray:
 
 
 def pair_still_close(pos, zonew, partner, r_tx2, access=None):
-    """O(N) row of the contact matrix at ``(i, partner[i])``.
+    """The contact-matrix entries ``close[i, partner[i]]``, one per node.
 
     ``zonew`` is the ``(N,)`` uint32 zone-membership word
     (``repro.kernels.contacts.zone_words``); the pair is still close iff
@@ -106,10 +111,11 @@ def pair_still_close(pos, zonew, partner, r_tx2, access=None):
     zonew = apply_access(zonew, access)
     n = pos.shape[0]
     pidx = jnp.clip(partner, 0, n - 1)
-    dx = pos[:, 0] - pos[pidx, 0]
-    dy = pos[:, 1] - pos[pidx, 1]
+    ppos, pzone = take_nodes((pos, zonew), pidx)
+    dx = pos[:, 0] - ppos[:, 0]
+    dy = pos[:, 1] - ppos[:, 1]
     d2 = dx * dx + dy * dy
-    return (d2 <= r_tx2) & ((zonew & zonew[pidx]) != 0) \
+    return (d2 <= r_tx2) & ((zonew & pzone) != 0) \
         & (jnp.arange(n) != pidx)
 
 
@@ -210,8 +216,9 @@ def _deliveries_general(
         fin = t0 + (rank + 1).astype(jnp.float32) * T_L
         return sender_has & (fin <= eff)
 
-    delivered = jax.vmap(deliveries)(order_seed[pidx], snap_has[pidx], eff_time)
-    return delivered & ending[:, None], snap[pidx]
+    seed, has, words = take_nodes((order_seed, snap_has, snap), pidx)
+    delivered = jax.vmap(deliveries)(seed, has, eff_time)
+    return delivered & ending[:, None], words
 
 
 def compute_deliveries(
@@ -233,8 +240,9 @@ def compute_deliveries(
         # Bit-identical to the general path — pinned against it in
         # ``tests/test_sim_contacts.py``.
         fin = t0 + jnp.float32(1.0) * T_L
-        delivered = snap_has[pidx] & (fin <= eff_time)[:, None]
-        return delivered & ending[:, None], snap[pidx]
+        has, words = take_nodes((snap_has, snap), pidx)
+        delivered = has & (fin <= eff_time)[:, None]
+        return delivered & ending[:, None], words
 
     return _deliveries_general(
         order_seed=order_seed, snap_has=snap_has, snap=snap, pidx=pidx,
@@ -264,7 +272,7 @@ def form_connections(
     midx = jnp.clip(match, 0, n - 1)
 
     n_own = jnp.sum(has_model, axis=-1)
-    n_exch = n_own + n_own[midx]
+    n_exch = n_own + take_nodes(n_own, midx)
     total = t0 + n_exch.astype(jnp.float32) * T_L
     partner = jnp.where(newly, match, partner)
     exch_elapsed = jnp.where(newly, 0.0, exch_elapsed)

@@ -670,8 +670,8 @@ def _run(key, p_dyn: dict, cfg: SimConfig, M: int, trace: str = "full"):
                 did_train = (
                     fin_train
                     & (state.serv_model == learning.LEARN_MODEL)
-                    & (obs_birth[learning.LEARN_MODEL, state.serv_slot]
-                       > -jnp.inf)
+                    & (compute.take_nodes(obs_birth[learning.LEARN_MODEL],
+                                          state.serv_slot) > -jnp.inf)
                 )
                 theta, theta_cnt, theta_age = learning.train_completions(
                     lc, task, slot_idx, did_train, theta, theta_cnt, theta_age,

@@ -260,7 +260,7 @@ def link_fail(k, p_link, partner):
     n = partner.shape[0]
     pidx = jnp.clip(partner, 0, n - 1)
     u = jax.random.uniform(k, (n,))
-    return (u < p_link) | (u[pidx] < p_link)
+    return (u < p_link) | (compute.take_nodes(u, pidx) < p_link)
 
 
 def abort_matches(k, p_abort, match):
@@ -274,7 +274,7 @@ def abort_matches(k, p_abort, match):
         jnp.arange(n, dtype=match.dtype), jnp.clip(match, 0, n - 1)
     )
     u = jax.random.uniform(k, (n,))
-    aborted = (match >= 0) & (u[pair_lo] < p_abort)
+    aborted = (match >= 0) & (compute.take_nodes(u, pair_lo) < p_abort)
     return jnp.where(aborted, -1, match), aborted
 
 
@@ -284,7 +284,7 @@ def gate_deliveries(delivered, pidx, is_free_rider):
     ``delivered`` is the (N, M) receiver-side delivery flags and ``pidx``
     the clipped partner (sender) index; a free-rider still receives (its
     own row is untouched) but never appears as a server."""
-    return delivered & ~is_free_rider[pidx][:, None]
+    return delivered & ~compute.take_nodes(is_free_rider, pidx)[:, None]
 
 
 def fault_outputs(*, on, in_rz, has_model, cls1h, n_per_class,

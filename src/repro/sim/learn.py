@@ -78,6 +78,7 @@ from repro.kernels.gossip_merge import (
 )
 from repro.models import tiny
 from repro.optim.optimizers import sgd
+from repro.sim.compute import take_nodes
 
 __all__ = ["LearnConfig", "LearnTask", "make_task", "init_fields",
            "reset_replicas", "merge_deliveries", "snapshot_params",
@@ -289,13 +290,15 @@ def merge_deliveries(lc: LearnConfig, received, pidx, theta, theta_cnt,
     fields (only the gated-in ones present).
     """
     n = theta.shape[0]
+    # the parameter rows stay an indexed gather: on the TPU a one-hot
+    # select of D words per row costs more than this row gather
     peer_theta = theta_snap[pidx]
-    peer_cnt = snap_cnt[pidx]
-    peer_age = snap_age[pidx]
-    peer_poison = (
-        snap_poison[pidx] if snap_poison is not None
-        else jnp.zeros((n,), bool)
-    )
+    if snap_poison is not None:
+        peer_cnt, peer_age, peer_poison = take_nodes(
+            (snap_cnt, snap_age, snap_poison), pidx)
+    else:
+        peer_cnt, peer_age = take_nodes((snap_cnt, snap_age), pidx)
+        peer_poison = jnp.zeros((n,), bool)
 
     # (1) non-finite entry guard: a corrupted payload or bookkeeping skips
     # the merge entirely (the receiver keeps its replica untouched)

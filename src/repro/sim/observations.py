@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.sim.compute import (packed_onehot, packed_popcount, pack_mask,
-                               shared_barrier, unpack_mask)
+                               shared_barrier, take_nodes, unpack_mask)
 
 __all__ = ["generate_observations", "apply_completions", "slot_outputs",
            "estimate_o_of_tau"]
@@ -118,7 +118,7 @@ def apply_completions(
     has_model = has_model | (fin_merge[:, None] & onehot_m)
 
     # fresh[n, m] = obs_birth[m, serv_slot[n]] > -inf (no (N, M, K) copy)
-    fresh = jnp.take(obs_birth, serv_slot, axis=1).T > -jnp.inf
+    fresh = take_nodes(obs_birth.T, serv_slot) > -jnp.inf
     onehot_kw = packed_onehot(serv_slot, k_count)                   # (N, KW)
     inc = inc | jnp.where(
         (fin_train[:, None] & onehot_m & fresh)[:, :, None],

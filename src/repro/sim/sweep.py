@@ -63,6 +63,7 @@ import numpy as np
 from repro import spans
 from repro.core.meanfield import FGParams
 from repro.sharding.logical import SWEEP_RULES, spec_for
+from repro.sim.compute import take_path
 from repro.sim.engine import (
     BatchSimOutputs, SimConfig, _check_params, _run, _sample_times,
     stack_dynamic_params,
@@ -737,7 +738,9 @@ def run(
                   instead of recompiling.
 
     An in-process call records its host phases as ``repro.spans``
-    spans: an ``fg.sweep`` root with ``fg.sweep.prepare``, ``.keys``,
+    spans: an ``fg.sweep`` root (attr ``take``: how the engine step
+    reads its per-node tables, :func:`repro.sim.compute.take_path`)
+    with ``fg.sweep.prepare``, ``.keys``,
     ``.dispatch`` and ``.pull`` per chunk, ``.checkpoint`` and
     ``.finalize``.
 
@@ -757,7 +760,8 @@ def run(
             queue_dir=queue_dir, xla_cache_dir=xla_cache_dir,
         )
 
-    with spans.span("fg.sweep", reduce=reduce, slots=cfg.n_slots) as root:
+    with spans.span("fg.sweep", reduce=reduce, slots=cfg.n_slots,
+                    take=take_path(cfg.n_nodes)) as root:
         with spans.span("fg.sweep.prepare"):
             setup = _prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size,
                              quantiles, tau_grid, n_devices)

@@ -110,7 +110,7 @@ def test_sweep_records_one_tree_per_call_in_chunks():
     root = spans.recent("fg.sweep")[-1]
     assert root.parent is None and not root.failed
     assert root.attrs == {"reduce": "mean", "slots": 160, "runs": 4,
-                          "chunks": 2}
+                          "chunks": 2, "take": "onehot"}
     assert out.plan.n_chunks == 2
     # double buffered: chunk 1 is dispatched before chunk 0 is pulled
     assert _tree_names(root) == [

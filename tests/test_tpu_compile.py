@@ -176,6 +176,35 @@ def test_sharded_sweep_program_compiles_for_four_chips(topo, monkeypatch):
     assert _kernel_names(text) == {"pairwise_contacts"}
 
 
+_GATHER = re.compile(r'^\s*(?:ROOT )?%\S+ = \S+ gather\(.*op_name="([^"]*)"')
+
+
+@pytest.mark.parametrize("learn", [False, True], ids=["protocol", "learning"])
+def test_dense_engine_step_reads_node_tables_without_gathers(
+        one_chip, monkeypatch, learn):
+    """The dense slot step (N = 200, M = 1) reads its per-node tables by
+    one-hot select: its compiled program holds no gather, and with
+    learning on only the row gather of the parameter snapshots
+    (``theta_snap[pidx]`` in ``fg.learn.merge``). The program scans a
+    few slots: a single slot from the initial state would fold its
+    partner reads into constants."""
+    from repro.configs.fg_learn import logreg_task
+    from repro.configs.fg_paper import paper_params
+    from repro.sim import SimConfig, engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = SimConfig(n_slots=16, sample_every=8,
+                    learn=logreg_task() if learn else None)
+    params = {k: jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+              for k in engine.dynamic_params(paper_params())}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(lambda k, p: engine._run(k, p, cfg, 1)).lower(
+        key, params).compile().as_text()
+    scopes = [[c for c in m.group(1).split("/") if c.startswith("fg.")][-1]
+              for m in map(_GATHER.match, text.splitlines()) if m]
+    assert scopes == (["fg.learn.merge"] if learn else [])
+
+
 def test_city_grid_slice_is_the_real_grid():
     """The grid slice above is cut from the grid ``make_grid`` builds for
     the N = 32768 paper-density deployment."""
