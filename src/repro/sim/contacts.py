@@ -204,20 +204,22 @@ def _deliveries_general(
     *, order_seed, snap_has, snap, pidx, eff_time, ending, t0, T_L
 ):
     """The any-M delivery path: per-connection random send order (one
-    threefry hash per node per slot), rank via double argsort."""
+    threefry hash per node per slot), rank via double argsort, both under
+    the scope ``fg.deliveries.order``."""
     m_count = snap_has.shape[1]
 
-    def deliveries(order_seed_i, sender_has, eff):
+    def send_rank(order_seed_i, sender_has):
         rnd = jax.random.uniform(
             jax.random.fold_in(jax.random.PRNGKey(0), order_seed_i), (m_count,)
         )
         rnd = jnp.where(sender_has, rnd, jnp.inf)
-        rank = jnp.argsort(jnp.argsort(rnd))  # 0-based among all models
-        fin = t0 + (rank + 1).astype(jnp.float32) * T_L
-        return sender_has & (fin <= eff)
+        return jnp.argsort(jnp.argsort(rnd))  # 0-based among all models
 
     seed, has, words = take_nodes((order_seed, snap_has, snap), pidx)
-    delivered = jax.vmap(deliveries)(seed, has, eff_time)
+    with jax.named_scope("fg.deliveries.order"):
+        rank = jax.vmap(send_rank)(seed, has)
+    fin = t0 + (rank + 1).astype(jnp.float32) * T_L
+    delivered = has & (fin <= eff_time[:, None])
     return delivered & ending[:, None], words
 
 

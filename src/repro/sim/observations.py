@@ -30,25 +30,28 @@ __all__ = ["generate_observations", "apply_completions", "slot_outputs",
 
 #: Observer-rank implementation switch: at or below this node count the
 #: O(N²) compare-reduce wins on CPU (it vectorizes where XLA's CPU sort
-#: runs a scalar comparator loop); above it the O(N log N)
-#: sort+searchsorted form keeps the whole step sub-quadratic (the cells
-#: contact backend's regime). Both compute the identical rank — the
-#: number of scores *strictly below* one's own, ties included — so the
-#: selected observer set is the same at any N.
+#: runs a scalar comparator loop); above it the O(N log N) double stable
+#: argsort keeps the whole step sub-quadratic (the cells contact backend's
+#: regime). Both compute the identical rank — the node's position in the
+#: row sorted by (score, node id) — so the selected observer set is the
+#: same at any N.
 RANK_DENSE_MAX_N = 512
 
 
 def _observer_ranks(who_scores: jnp.ndarray) -> jnp.ndarray:
-    """(M, N) rank of each node's score among its row: #scores < own."""
+    """(M, N) position of each node in its row sorted by (score, node id):
+    the number of scores below its own plus the number of equal scores of
+    lower node ids. A tie of f32 scores goes to the lower id, as in a
+    stable sort, so ``rank < Λ`` admits exactly Λ nodes."""
     n = who_scores.shape[1]
     if n <= RANK_DENSE_MAX_N:
-        return jnp.sum(
-            who_scores[:, :, None] > who_scores[:, None, :], axis=-1
-        )
-    srt = jnp.sort(who_scores, axis=-1)
-    return jax.vmap(
-        lambda s, v: jnp.searchsorted(s, v, side="left")
-    )(srt, who_scores).astype(jnp.int32)
+        own = who_scores[:, :, None]
+        other = who_scores[:, None, :]
+        lower_id = jnp.arange(n)[None, :] < jnp.arange(n)[:, None]
+        return jnp.sum(jnp.where(lower_id, other <= own, other < own),
+                       axis=-1)
+    order = jnp.argsort(who_scores, axis=-1, stable=True)
+    return jnp.argsort(order, axis=-1, stable=True)
 
 
 def generate_observations(
@@ -77,15 +80,13 @@ def generate_observations(
     inc = inc & ~recycled[None]
 
     # Λ random in-RZ nodes record each new observation. Score nodes i.i.d.
-    # (out-of-RZ nodes pushed to the back) and take the Λ smallest scores —
-    # identical to the legacy top-Λ gather, but Λ stays dynamic (a traced
-    # threshold, not a static slice), so scenario batches can sweep it.
-    # Selection is expressed through each node's *rank* (#scores strictly
-    # below its own) rather than a sort + k-th-value threshold: "rank < Λ"
-    # picks exactly the same set as "score <= Λ-th smallest" — including
-    # under f32 score ties, where both forms admit every tied holder of the
-    # threshold value — while the O(N²) compare-reduce vectorizes where
-    # XLA's CPU sort lowers to a scalar comparator loop. Like the scores
+    # (out-of-RZ nodes pushed to the back) and take the Λ smallest scores,
+    # f32 ties to the lower node id — the first Λ of a stable argsort, but
+    # Λ stays dynamic (a traced threshold, not a static slice), so scenario
+    # batches can sweep it. Selection is expressed through each node's
+    # *rank* (its position in the (score, id) order) rather than a sort,
+    # while the O(N²) compare-reduce vectorizes where XLA's CPU sort
+    # lowers to a scalar comparator loop. Like the scores
     # themselves, the rank matrix depends only on the per-seed key chain,
     # so sweep batches compute it once per seed, not once per scenario.
     who_scores = jax.random.uniform(k_who, (m_count, n)) + (~in_rz)[None, :] * 1e3

@@ -739,7 +739,8 @@ def run(
 
     An in-process call records its host phases as ``repro.spans``
     spans: an ``fg.sweep`` root (attr ``take``: how the engine step
-    reads its per-node tables, :func:`repro.sim.compute.take_path`)
+    reads its per-node tables, :func:`repro.sim.compute.take_path`;
+    attr ``models``: the model count M)
     with ``fg.sweep.prepare``, ``.keys``,
     ``.dispatch`` and ``.pull`` per chunk, ``.checkpoint`` and
     ``.finalize``.
@@ -767,7 +768,7 @@ def run(
                              quantiles, tau_grid, n_devices)
         plan = setup.plan
         root.attrs.update(runs=plan.n_scenarios * plan.n_seeds,
-                          chunks=plan.n_chunks)
+                          chunks=plan.n_chunks, models=setup.M)
         with spans.span("fg.sweep.keys"):
             keys = setup.keys()
         return _run_chunks(setup, keys, seeds, checkpoint_dir, resume,

@@ -155,3 +155,25 @@ def test_lambda_is_sweepable_in_one_batch():
     low = batch.stored_info[0, :, s0:].mean()
     high = batch.stored_info[1, :, s0:].mean()
     assert high > low
+
+
+@pytest.mark.parametrize("dense", [True, False], ids=["compare", "sort"])
+def test_observer_ranks_break_score_ties_by_node_id(monkeypatch, dense):
+    """Both rank forms give each node its position in the (score, id)
+    order, so under f32 score ties ``rank < Λ`` picks exactly the first Λ
+    of a stable argsort (the reference's and the legacy step's rule)."""
+    import jax.numpy as jnp
+
+    from repro.sim import observations
+
+    if not dense:
+        monkeypatch.setattr(observations, "RANK_DENSE_MAX_N", 0)
+    scores = jnp.asarray([[0.5, 0.25, 0.25, 1e3, 0.25, 0.125, 1e3],
+                          [0.75, 0.75, 0.75, 0.75, 0.5, 0.5, 0.0]],
+                         jnp.float32)
+    rank = np.asarray(observations._observer_ranks(scores))
+    want = np.argsort(np.argsort(np.asarray(scores), axis=-1,
+                                 kind="stable"), axis=-1, kind="stable")
+    np.testing.assert_array_equal(rank, want)
+    for lam in range(1, 8):
+        assert ((rank < lam).sum(axis=-1) == lam).all()

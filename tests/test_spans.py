@@ -110,7 +110,7 @@ def test_sweep_records_one_tree_per_call_in_chunks():
     root = spans.recent("fg.sweep")[-1]
     assert root.parent is None and not root.failed
     assert root.attrs == {"reduce": "mean", "slots": 160, "runs": 4,
-                          "chunks": 2, "take": "onehot"}
+                          "chunks": 2, "take": "onehot", "models": 1}
     assert out.plan.n_chunks == 2
     # double buffered: chunk 1 is dispatched before chunk 0 is pulled
     assert _tree_names(root) == [
@@ -123,6 +123,13 @@ def test_sweep_records_one_tree_per_call_in_chunks():
             if s.name == "fg.sweep.pull"] == [0, 1]
     # the first call of this shape compiled its chunk program on dispatch
     assert root.ns >= sum(s.ns for s in tree if s.parent == root.id)
+
+
+@pytest.mark.parametrize("M", [1, 3])
+def test_sweep_root_records_the_model_count(M):
+    ps = [paper_params(lam=0.1, M=M, T_T=0.5, T_M=0.25)]
+    sweep.run(ps, CFG, (0,), reduce="mean")
+    assert spans.recent("fg.sweep")[-1].attrs["models"] == M
 
 
 def test_checkpointed_sweep_tree_and_latency_from_spans(tmp_path):

@@ -205,6 +205,38 @@ def test_dense_engine_step_reads_node_tables_without_gathers(
     assert scopes == (["fg.learn.merge"] if learn else [])
 
 
+_SORT = re.compile(r'^\s*(?:ROOT )?%([A-Za-z0-9_.\-]+?)(?:\.\d+)? = .*\ssort\('
+                   r'.*op_name="([^"]*)"')
+
+
+@pytest.mark.parametrize("M", [1, 25])
+def test_engine_step_sorts_only_the_send_order(one_chip, monkeypatch, M):
+    """The slot step at M = 25 holds the sorts of the per-connection send
+    order (the double argsort of ``contacts._deliveries_general``), named
+    as ``send_order_share`` reads them and scoped ``fg.deliveries.order``;
+    the M = 1 step holds no sort."""
+    from repro.configs.fg_paper import paper_params
+    from repro.sim import SimConfig, engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = SimConfig(n_slots=16, sample_every=8)
+    params = {k: jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+              for k in engine.dynamic_params(paper_params(M=M))}
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = jax.jit(lambda k, p: engine._run(k, p, cfg, M)).lower(
+        key, params).compile().as_text()
+    sorts = [m.groups() for m in map(_SORT.match, text.splitlines()) if m]
+    if M == 1:
+        assert sorts == []
+        return
+    assert len(sorts) == 2
+    names = set(_reader_names("send_order_share"))
+    for base, op_name in sorts:
+        assert base in names, base
+        assert [c for c in op_name.split("/")
+                if c.startswith("fg.")][-1] == "fg.deliveries.order"
+
+
 def test_city_grid_slice_is_the_real_grid():
     """The grid slice above is cut from the grid ``make_grid`` builds for
     the N = 32768 paper-density deployment."""
