@@ -51,6 +51,8 @@ This is verified bit-for-bit against a reference per-``M`` loop in
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -61,7 +63,7 @@ __all__ = [
     "enqueue_ascending", "pick_next_jobs", "advance_timers",
     "pack_mask", "unpack_mask", "packed_onehot", "packed_any",
     "packed_popcount", "shared_barrier", "take_nodes", "take_path",
-    "TAKE_SELECT_MAX",
+    "take_paths", "TAKE_SELECT_MAX", "TAKE_MXU_MIN_WORDS",
 ]
 
 #: Longest table :func:`take_nodes` reads by a one-hot select; longer
@@ -70,45 +72,92 @@ __all__ = [
 #: must stay O(N) per read.
 TAKE_SELECT_MAX = AUTO_CELLS_MIN_N
 
+#: Narrowest row, in 32-bit words, that :func:`take_nodes` reads by a
+#: one-hot MXU product rather than the select. On a TPU v5e the product
+#: is the faster from 4 words at 200 rows and from 2 at 64, 200 readers
+#: (``scripts/take_crossover.py``). Never below 4: the reads of the
+#: single-model step (at most 3 words) keep the select.
+TAKE_MXU_MIN_WORDS = 4
 
-def take_path(n: int) -> str:
-    """How :func:`take_nodes` reads a table of ``n`` rows: ``"onehot"``
-    (the select) or ``"index"`` (the gather)."""
-    return "onehot" if n <= TAKE_SELECT_MAX else "index"
+#: Every answer of :func:`take_path`, in the order :func:`take_paths`
+#: names them.
+TAKE_PATHS = ("index", "onehot", "mxu")
+
+
+def take_path(rows: int, words: int) -> str:
+    """How :func:`take_nodes` reads a table of ``rows`` rows of ``words``
+    32-bit words each (a bool column counts one word): ``"index"`` (the
+    gather), ``"onehot"`` (the select) or ``"mxu"`` (the product)."""
+    if rows > TAKE_SELECT_MAX:
+        return "index"
+    return "mxu" if words >= TAKE_MXU_MIN_WORDS else "onehot"
+
+
+def take_paths(reads) -> str:
+    """The paths a set of ``(rows, words)`` reads take, joined by ``+`` in
+    :data:`TAKE_PATHS` order (``"onehot+mxu"``)."""
+    used = {take_path(rows, words) for rows, words in reads}
+    return "+".join(p for p in TAKE_PATHS if p in used)
 
 
 def take_nodes(table, idx: jnp.ndarray):
     """``table[idx]`` for a per-node (or per-slot) table and a vector of
     row indices in ``[-L, L)``, ``L = table.shape[0]`` (a negative index
     wraps, as in ``table[idx]``). ``table`` may be a tuple of tables with
-    the same rows, read through one select: the result is then the tuple
-    of their reads.
+    the same rows, read through one select or product: the result is then
+    the tuple of their reads. Tables of 4-byte dtypes or bool.
 
-    Up to :data:`TAKE_SELECT_MAX` rows the read is a one-hot select: an
-    ``(L, N)`` compare of the row ids against ``idx`` (table rows on
-    sublanes, readers on lanes), the matching row kept as uint32 bits and
-    OR-reduced over the rows. An indexed gather on the TPU is a serial
-    loop of ~10 ns per element, while the select is a few dense vector
-    passes. Bits move unchanged, so the result is bitwise ``table[idx]``
-    for every value (NaN, ``-0.0``, ``±inf``); a float sum or an MXU
-    product would not be. Tables of 4-byte dtypes or bool; longer tables
-    trace exactly ``table[idx]``."""
+    The path is :func:`take_path` of the rows and the words per row of all
+    the tables together. Up to :data:`TAKE_SELECT_MAX` rows the read is
+    one-hot, since an indexed gather on the TPU is a serial loop of
+    ~10 ns per element:
+
+    * narrow rows, a select under the scope ``fg.take``: an ``(L, N)``
+      compare of the row ids against ``idx`` (table rows on sublanes,
+      readers on lanes), the matching row kept as uint32 bits and
+      OR-reduced over the rows;
+    * rows of :data:`TAKE_MXU_MIN_WORDS` words or more, a matrix product
+      under ``fg.take`` / ``fg.take.mxu``: each word split into four 8-bit
+      planes (a bool column into one), held in bfloat16, which is exact
+      for 0-255, and multiplied by the ``(N, L)`` bfloat16 one-hot with
+      float32 accumulation. Each output sums one non-zero term, so it is
+      the plane exactly, and the planes join back into the words.
+
+    Both move bits unchanged, so the result is bitwise ``table[idx]`` for
+    every value (NaN payloads, ``-0.0``, ``±inf``). Longer tables trace
+    exactly ``table[idx]``."""
     tables = table if isinstance(table, tuple) else (table,)
-    if take_path(tables[0].shape[0]) == "index":
+    path = take_path(tables[0].shape[0],
+                     sum(math.prod(t.shape[1:]) for t in tables))
+    if path == "index":
         got = tuple(t[idx] for t in tables)
     else:
         with jax.named_scope("fg.take"):
-            got = _take_select(tables, idx)
+            if path == "mxu":
+                with jax.named_scope("fg.take.mxu"):
+                    got = _take_mxu(tables, idx)
+            else:
+                got = _take_select(tables, idx)
     return got if isinstance(table, tuple) else got[0]
+
+
+def _words(t) -> jnp.ndarray:
+    """``t`` as ``(L, W)`` uint32 words (a bool as 0 / 1)."""
+    w = (t.astype(jnp.uint32) if t.dtype == jnp.bool_
+         else jax.lax.bitcast_convert_type(t, jnp.uint32))
+    return w.reshape(t.shape[0], -1)
+
+
+def _from_words(word, t, idx):
+    """The inverse of :func:`_words` for ``idx``'s readers of ``t``."""
+    word = word.reshape(idx.shape + t.shape[1:])
+    return (word != 0 if t.dtype == jnp.bool_
+            else jax.lax.bitcast_convert_type(word, t.dtype))
 
 
 def _take_select(tables, idx):
     n = tables[0].shape[0]
-    cols = [
-        (t.astype(jnp.uint32) if t.dtype == jnp.bool_
-         else jax.lax.bitcast_convert_type(t, jnp.uint32)).reshape(n, -1)
-        for t in tables
-    ]
+    cols = [_words(t) for t in tables]
     bits = jnp.concatenate(cols, axis=1)
     idx = jnp.where(idx < 0, idx + n, idx)
     onehot = jnp.arange(n, dtype=idx.dtype)[:, None] == idx[None, :]
@@ -118,10 +167,41 @@ def _take_select(tables, idx):
     ).T                                                     # (N, W)
     out, w0 = [], 0
     for t, c in zip(tables, cols):
-        word = picked[:, w0:w0 + c.shape[1]].reshape(idx.shape + t.shape[1:])
+        out.append(_from_words(picked[:, w0:w0 + c.shape[1]], t, idx))
         w0 += c.shape[1]
-        out.append(word != 0 if t.dtype == jnp.bool_
-                   else jax.lax.bitcast_convert_type(word, t.dtype))
+    return tuple(out)
+
+
+#: Bit offsets of a word's four byte planes.
+_SHIFTS = (0, 8, 16, 24)
+
+
+def _n_planes(t) -> int:
+    return 1 if t.dtype == jnp.bool_ else len(_SHIFTS)
+
+
+def _take_mxu(tables, idx):
+    n = tables[0].shape[0]
+    cols = [_words(t) for t in tables]
+    # per table, plane k holds byte k of every word (a bool column has one)
+    planes = jnp.concatenate(
+        [(c >> s) & jnp.uint32(0xFF) for t, c in zip(tables, cols)
+         for s in _SHIFTS[:_n_planes(t)]], axis=1).astype(jnp.bfloat16)
+    idx = jnp.where(idx < 0, idx + n, idx)
+    onehot = (idx[:, None] == jnp.arange(n, dtype=idx.dtype)[None, :]
+              ).astype(jnp.bfloat16)                        # (N, L)
+    picked = jax.lax.dot_general(
+        onehot, planes, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(jnp.uint32)  # (N, P)
+    out, p0 = [], 0
+    for t, c in zip(tables, cols):
+        w = c.shape[1]
+        word = picked[:, p0:p0 + w]
+        for k in range(1, _n_planes(t)):
+            word = word | (picked[:, p0 + k * w:p0 + (k + 1) * w]
+                           << _SHIFTS[k])
+        p0 += _n_planes(t) * w
+        out.append(_from_words(word, t, idx))
     return tuple(out)
 
 

@@ -16,8 +16,8 @@ per-run best new-contact candidate + mutual-best matching). On TPU the
 fused Pallas kernel runs the whole sweep in the second stage instead.
 Only per-node work — the partner-proximity bit and the mutual-best
 check — remains here; its reads of a partner's row go through
-``repro.sim.compute.take_nodes`` (a one-hot select in the dense range, a
-gather above it). Exchange snapshots (``snap``) travel bit-packed as
+``repro.sim.compute.take_nodes`` (one-hot in the dense range: a select,
+or an MXU product for wide rows; a gather above it). Exchange snapshots (``snap``) travel bit-packed as
 well.
 
 This module is the *dense* contact backend. For large N the engine

@@ -47,6 +47,7 @@ __all__ = [
     "dynamic_params",
     "stack_dynamic_params",
     "scan_carry_bytes",
+    "take_reads",
 ]
 
 
@@ -798,6 +799,32 @@ def scan_carry_bytes(cfg: SimConfig, M: int) -> int:
         int(np.prod(leaf.shape)) * leaf.dtype.itemsize
         for leaf in jax.tree_util.tree_leaves(shapes)
     )
+
+
+def take_reads(cfg: SimConfig, M: int) -> tuple:
+    """``(rows, words)`` of the slot step's table reads by node or slot
+    index (:func:`repro.sim.compute.take_nodes`; a bool column counts one
+    word): what :func:`repro.sim.compute.take_paths` names the step's
+    read paths from. The fault layer's reads are of one word a node row,
+    as ``n_own``'s is."""
+    n, kw = cfg.n_nodes, -(-cfg.k_obs // 32)
+    reads = [
+        (n, 2),                        # mutualize: (best, has)
+        (n, 1),                        # form_connections: n_own
+        # deliveries: (snap_has, snap), and order_seed at M > 1
+        (n, 1 + kw) if M == 1 else (n, 1 + M + M * kw),
+        (cfg.k_obs, M),                # apply_completions: obs_birth.T
+    ]
+    if (cells.contact_backend(cfg) == "cells"
+            or jax.default_backend() == "tpu"):
+        # pair_still_close: (pos, zonew), where the step keeps no packed
+        # contact matrix to read the partner's bit from
+        reads.append((n, 3))
+    if cfg.learn is not None and cfg.learn.enabled:
+        adv = cfg.faults is not None and cfg.faults.adversarial
+        # the trained sample's birth; (snap_cnt, snap_age[, snap_poison])
+        reads += [(cfg.k_obs, 1), (n, 3 if adv else 2)]
+    return tuple(reads)
 
 
 def check_overflow(cfg: SimConfig, max_ovf, *, context: str = "run") -> int:

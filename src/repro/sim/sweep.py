@@ -63,10 +63,10 @@ import numpy as np
 from repro import spans
 from repro.core.meanfield import FGParams
 from repro.sharding.logical import SWEEP_RULES, spec_for
-from repro.sim.compute import take_path
+from repro.sim.compute import take_paths
 from repro.sim.engine import (
     BatchSimOutputs, SimConfig, _check_params, _run, _sample_times,
-    stack_dynamic_params,
+    stack_dynamic_params, take_reads,
 )
 
 __all__ = ["SweepPlan", "SweepSummary", "plan_sweep", "run", "REDUCERS"]
@@ -738,9 +738,10 @@ def run(
                   instead of recompiling.
 
     An in-process call records its host phases as ``repro.spans``
-    spans: an ``fg.sweep`` root (attr ``take``: how the engine step
-    reads its per-node tables, :func:`repro.sim.compute.take_path`;
-    attr ``models``: the model count M)
+    spans: an ``fg.sweep`` root (attr ``take``: the paths by which the
+    engine step reads its per-node tables, ``"onehot+mxu"`` say,
+    :func:`repro.sim.compute.take_paths`; attr ``models``: the model
+    count M)
     with ``fg.sweep.prepare``, ``.keys``,
     ``.dispatch`` and ``.pull`` per chunk, ``.checkpoint`` and
     ``.finalize``.
@@ -761,14 +762,15 @@ def run(
             queue_dir=queue_dir, xla_cache_dir=xla_cache_dir,
         )
 
-    with spans.span("fg.sweep", reduce=reduce, slots=cfg.n_slots,
-                    take=take_path(cfg.n_nodes)) as root:
+    with spans.span("fg.sweep", reduce=reduce, slots=cfg.n_slots) as root:
         with spans.span("fg.sweep.prepare"):
             setup = _prepare(ps, cfg, seeds, reduce, warmup_frac, chunk_size,
                              quantiles, tau_grid, n_devices)
         plan = setup.plan
         root.attrs.update(runs=plan.n_scenarios * plan.n_seeds,
-                          chunks=plan.n_chunks, models=setup.M)
+                          chunks=plan.n_chunks,
+                          take=take_paths(take_reads(cfg, setup.M)),
+                          models=setup.M)
         with spans.span("fg.sweep.keys"):
             keys = setup.keys()
         return _run_chunks(setup, keys, seeds, checkpoint_dir, resume,

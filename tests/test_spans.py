@@ -125,11 +125,16 @@ def test_sweep_records_one_tree_per_call_in_chunks():
     assert root.ns >= sum(s.ns for s in tree if s.parent == root.id)
 
 
-@pytest.mark.parametrize("M", [1, 3])
+@pytest.mark.parametrize("M", [1, 3, 25])
 def test_sweep_root_records_the_model_count(M):
+    """The root records M, and in ``take`` the engine step's read paths:
+    the select alone at M = 1 (no row is wider than 3 words), the select
+    and the MXU product once M widens the peer's rows."""
     ps = [paper_params(lam=0.1, M=M, T_T=0.5, T_M=0.25)]
     sweep.run(ps, CFG, (0,), reduce="mean")
-    assert spans.recent("fg.sweep")[-1].attrs["models"] == M
+    attrs = spans.recent("fg.sweep")[-1].attrs
+    assert attrs["models"] == M
+    assert attrs["take"] == ("onehot" if M == 1 else "onehot+mxu")
 
 
 def test_checkpointed_sweep_tree_and_latency_from_spans(tmp_path):

@@ -207,24 +207,47 @@ def test_dense_engine_step_reads_node_tables_without_gathers(
 
 _SORT = re.compile(r'^\s*(?:ROOT )?%([A-Za-z0-9_.\-]+?)(?:\.\d+)? = .*\ssort\('
                    r'.*op_name="([^"]*)"')
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_DIM_76 = re.compile(r"[\[,]76[\],]")
+
+
+def _last_scope(op_name: str) -> str:
+    return [c for c in op_name.split("/") if c.startswith("fg.")][-1]
+
+
+@pytest.fixture(scope="module")
+def step_text(one_chip):
+    """The compiled slot step (N = 200, a few slots) at model count M,
+    compiled once per M for the tests below."""
+    from repro.configs.fg_paper import paper_params
+    from repro.sim import SimConfig, engine
+
+    texts = {}
+
+    def compiled(M):
+        if M not in texts:
+            cfg = SimConfig(n_slots=16, sample_every=8)
+            params = {k: jax.ShapeDtypeStruct((), jnp.float32,
+                                              sharding=one_chip)
+                      for k in engine.dynamic_params(paper_params(M=M))}
+            key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: "tpu")
+                texts[M] = jax.jit(
+                    lambda k, p: engine._run(k, p, cfg, M)).lower(
+                        key, params).compile().as_text()
+        return texts[M]
+
+    return compiled
 
 
 @pytest.mark.parametrize("M", [1, 25])
-def test_engine_step_sorts_only_the_send_order(one_chip, monkeypatch, M):
+def test_engine_step_sorts_only_the_send_order(step_text, M):
     """The slot step at M = 25 holds the sorts of the per-connection send
     order (the double argsort of ``contacts._deliveries_general``), named
     as ``send_order_share`` reads them and scoped ``fg.deliveries.order``;
     the M = 1 step holds no sort."""
-    from repro.configs.fg_paper import paper_params
-    from repro.sim import SimConfig, engine
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = SimConfig(n_slots=16, sample_every=8)
-    params = {k: jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
-              for k in engine.dynamic_params(paper_params(M=M))}
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
-    text = jax.jit(lambda k, p: engine._run(k, p, cfg, M)).lower(
-        key, params).compile().as_text()
+    text = step_text(M)
     sorts = [m.groups() for m in map(_SORT.match, text.splitlines()) if m]
     if M == 1:
         assert sorts == []
@@ -233,8 +256,36 @@ def test_engine_step_sorts_only_the_send_order(one_chip, monkeypatch, M):
     names = set(_reader_names("send_order_share"))
     for base, op_name in sorts:
         assert base in names, base
-        assert [c for c in op_name.split("/")
-                if c.startswith("fg.")][-1] == "fg.deliveries.order"
+        assert _last_scope(op_name) == "fg.deliveries.order"
+
+
+@pytest.mark.parametrize("M", [1, 25])
+def test_engine_step_reads_wide_rows_by_a_dot(step_text, M):
+    """At M = 25 the slot step reads the peer's 76-word rows
+    (``order_seed``, ``snap_has``, ``snap``: 4 + 25 + 200 byte planes)
+    and ``obs_birth.T``'s 25-word rows (100 planes) by one-hot products
+    under ``fg.take.mxu``, which the TPU compiles as convolutions, and no
+    select of ``fg.take`` spans 76 words. Every read of the M = 1 step is
+    of at most 3 words: it holds no product under ``fg.take``."""
+    dots, wide_selects = [], []
+    for ln in step_text(M).splitlines():
+        m = _OP_NAME.search(ln)
+        if not m or "fg.take" not in m.group(1):
+            continue
+        head = ln.split("metadata=", 1)[0]
+        if " convolution(" in head or " dot(" in head:
+            dots.append((_last_scope(m.group(1)), head.split(" = ")[1]))
+        elif (_last_scope(m.group(1)) == "fg.take"
+              and _DIM_76.search(head)):
+            wide_selects.append(head)
+    if M == 1:
+        assert dots == []
+        return
+    assert wide_selects == []
+    assert {scope for scope, _ in dots} == {"fg.take.mxu"}
+    planes = sorted(int(re.match(r"f32\[200,(\d+)\]", shape).group(1))
+                    for _, shape in dots)
+    assert planes == [100, 229]
 
 
 def test_city_grid_slice_is_the_real_grid():
