@@ -26,9 +26,6 @@ from benchmarks import (  # noqa: E402
     fig1_availability, fig2_capacity, fig3_stability, fig4_staleness,
     fig_adversarial, fig_convergence, fig_faults, fig_learning,
     fig_multizone,
-    gossip_throughput,
-    roofline_table,
-    sim_engine,
 )
 
 BENCHES = {
@@ -41,9 +38,6 @@ BENCHES = {
     "fig_faults": fig_faults.main,
     "fig_learning": fig_learning.main,
     "fig_multizone": fig_multizone.main,
-    "gossip": gossip_throughput.main,
-    "roofline": roofline_table.main,
-    "sim_engine": sim_engine.main,
 }
 
 

@@ -4,17 +4,19 @@
 Run from the root of the checkout, in a process of its own (it holds the
 chip):
 
-    python chip_smoke.py               # one chip: phases (a)-(d)
-    python chip_smoke.py --four-chips  # the phase (a) study sharded over
-                                       # four chips against one device
+    python chip_smoke.py               # one chip: phases (b) and (c)
+    python chip_smoke.py --four-chips  # phase (a) sharded over four
+                                       # chips against one device
 
-Phases, in order, all through the entry points a user calls:
+Phases, all through the entry points a user calls:
 
-  (a) paper study: the §VI deployment (``SimConfig()``: 200 nodes in
-      200 m x 200 m, RZ radius 100 m, RDM at 1 m/s, dt = 0.25 s, 8000
-      slots) at ``paper_params(M=1)``, 8 values of λ x 16 seeds through
-      ``sweep.run(reduce="mean")``; the simulated availability at each λ
-      against the Lemma-1 fixed point (``solve_fixed_point``), within 15%.
+  (a) paper study, with ``--four-chips`` only: the §VI deployment
+      (``SimConfig()``: 200 nodes in 200 m x 200 m, RZ radius 100 m, RDM
+      at 1 m/s, dt = 0.25 s, 8000 slots) at ``paper_params(M=1)``, 8
+      values of λ x 16 seeds through ``sweep.run(reduce="mean")``; the
+      simulated availability at each λ against the Lemma-1 fixed point
+      (``solve_fixed_point``), within 15%, on four chips and on one; the
+      two runs' reductions must be equal bit for bit.
   (b) the compiled contact kernels against their jnp references on seeded
       positions: ``pairwise_contacts`` at N = 200 and 4096,
       ``cell_close_words`` on the city grid of phase (c); every output
@@ -22,8 +24,9 @@ Phases, in order, all through the entry points a user calls:
   (c) city-scale run: ``simulate`` with the cells contact backend at
       N = 32768 at the paper's density, a few hundred slots; no neighbour
       list may overflow.
-  (d) learning on: ``sweep.run`` with ``SimConfig(learn=logreg_task())``
-      at the paper geometry; holder test accuracy must rise.
+
+The benchmark (``bench/run.py``) runs the one-chip study and the learning
+sweep on every change, against its plain reference.
 
 Each phase prints one JSON line (device, compile and wall seconds, what it
 compared, the result). The kernels must run compiled: each phase checks
@@ -76,7 +79,7 @@ def _compiled_kernel(calls: list) -> bool:
     return bool(calls) and not any(calls)
 
 
-def phase_paper_study(n_devices=None):
+def phase_paper_study(n_devices):
     """(a): availability of the §VI study against Lemma 1."""
     import numpy as np
 
@@ -205,30 +208,6 @@ def phase_city():
     return ok, "nbr_overflow == 0 on the Pallas cell path", detail, None
 
 
-def phase_learning():
-    """(d): gossip learning through the compiled row-merge kernel."""
-    import numpy as np
-
-    from repro.configs.fg_learn import logreg_task
-    from repro.configs.fg_paper import paper_params
-    from repro.kernels import gossip_merge
-    from repro.sim import SimConfig, sweep
-
-    cfg = SimConfig(learn=logreg_task(), n_slots=4000, sample_every=40)
-    calls: list = []
-    with _kernel_calls(gossip_merge, "_rows_pallas", calls):
-        out = sweep.run([paper_params(lam=0.05, M=1)], cfg, tuple(range(4)),
-                        reduce="trace")
-    acc = np.asarray(out.test_acc_holders, np.float64)[0]   # (seeds, samples)
-    k = max(acc.shape[1] // 10, 1)
-    start, end = float(np.mean(acc[:, :k])), float(np.mean(acc[:, -k:]))
-    ok = end > start and bool(np.all(np.isfinite(acc))) \
-        and _compiled_kernel(calls)
-    detail = dict(acc_start=start, acc_end=end, samples=acc.shape[1],
-                  kernel_compiled=_compiled_kernel(calls))
-    return ok, "holder test accuracy, first vs last tenth", detail, None
-
-
 def _run_phase(name, fn, device, failures, *args):
     """Run one phase as a span of its own: its compile seconds are the
     backend compilations counted on the phase's spans (tracing and
@@ -313,10 +292,8 @@ def main(argv=None) -> int:
     if args.four_chips:
         _four_chips(device, failures)
     else:
-        for name, fn in (("a_paper_study", phase_paper_study),
-                         ("b_kernels_vs_ref", phase_kernels),
-                         ("c_city_cells", phase_city),
-                         ("d_learning", phase_learning)):
+        for name, fn in (("b_kernels_vs_ref", phase_kernels),
+                         ("c_city_cells", phase_city)):
             _run_phase(name, fn, device, failures)
     if failures:
         print(f"chip_smoke: failed phases {failures}", file=sys.stderr)

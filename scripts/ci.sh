@@ -1,8 +1,7 @@
 #!/usr/bin/env bash
-# CI entry point: tier-1 test suite + a fast batched-simulation smoke
-# benchmark (the sim_engine bench doubles as a perf regression canary —
-# its derived line reports the batched-vs-serial speedup and the
-# host-transfer reduction of the on-device-reduced sweep path).
+# CI entry point: the tier-1 test suite and the invariant gates below.
+# Speed is measured on the chip by the benchmark (bench/, PERF.md), not
+# here.
 #
 # The tier-1 suite runs twice: once with the default single XLA CPU
 # device and once with 2 forced host devices, so both the single-device
@@ -18,9 +17,8 @@
 # regressions (tests silently dropping out of a lane) are visible in
 # the log diff.
 #
-# Usage:  bash scripts/ci.sh [--bench-smoke] [--chaos-smoke]
-#                            [--adversarial-smoke] [--nightly]
-#                            [extra pytest args...]
+# Usage:  bash scripts/ci.sh [--chaos-smoke] [--adversarial-smoke]
+#                            [--nightly] [extra pytest args...]
 #
 #   --adversarial-smoke  gate the Byzantine layer's two invariants:
 #                   (a) a zero-rate adversarial config (honest classes,
@@ -47,37 +45,17 @@
 #                   Also runs the full fig_learning sweep and fails
 #                   unless the measured Gossip-Learning accuracy ordering
 #                   agrees with the Theorem 2 capacity ordering.
-#   --bench-smoke   additionally gate on sweep performance: run the quick
-#                   sim_engine bench and fail if (a) the same-run
-#                   reduced-sweep/serial speedup ratio regressed more than 30%
-#                   against the checked-in BENCH_sim_engine.json baseline,
-#                   or (b) the reduced-output sweep path ships less than
-#                   10x fewer bytes to the host than the full-trace path.
-#                   Also runs the large-N contact-backend smoke: one
-#                   N=4096 scaling measurement, failing unless the
-#                   cell-list backend beats the dense O(N²) sweep by
-#                   >= 2x (the checked-in pr5 rows show ~2.9x here and
-#                   8x at N=8192; 2x leaves noise headroom) with zero
-#                   neighbor-list overflow.
-#                   The speedup ratio scales with the device (core)
-#                   count, so that gate only enforces when the host
-#                   exposes the same number of XLA devices the baseline
-#                   was recorded on (n_devices in the baseline file) — on
-#                   other hosts it reports and passes, asking for a
-#                   baseline regeneration.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-BENCH_SMOKE=0
 CHAOS_SMOKE=0
 ADV_SMOKE=0
 NIGHTLY=0
 ARGS=()
 for a in "$@"; do
   case "$a" in
-    --bench-smoke)       BENCH_SMOKE=1 ;;
     --chaos-smoke)       CHAOS_SMOKE=1 ;;
     --adversarial-smoke) ADV_SMOKE=1 ;;
     --nightly)           NIGHTLY=1 ;;
@@ -314,10 +292,6 @@ EOF
   echo "OK"
 fi
 
-echo
-echo "=== smoke: batched simulation engine (quick) ==="
-python -m benchmarks.run --quick --only sim_engine
-
 if [ "$NIGHTLY" = "1" ]; then
   echo
   echo "=== nightly: Gossip-Learning capacity-ordering sweep (fig_learning) ==="
@@ -328,80 +302,4 @@ if [ "$NIGHTLY" = "1" ]; then
   grep -q "ordering_ok=True" /tmp/fig_learning.out \
     || { echo "FAIL: measured accuracy ordering disagrees with Theorem 2"; \
          exit 1; }
-fi
-
-if [ "$BENCH_SMOKE" = "1" ]; then
-  echo
-  echo "=== bench-smoke: throughput + transfer regression gates ==="
-  python - <<'EOF'
-import json, sys
-
-with open("reports/bench/sim_engine.json") as f:
-    current = json.load(f)
-with open("BENCH_sim_engine.json") as f:
-    base = json.load(f)
-
-rows = {r["mode"]: r for r in current["rows"]}
-serial, batched = rows["serial"], rows["batched"]
-reduced = rows["batched_reduced"]
-
-ratio = reduced["slots_runs_per_s"] / serial["slots_runs_per_s"]
-ref = base["quick_baseline"]["reduced_over_serial_speedup_x"]
-base_ndev = base["quick_baseline"]["n_devices"]
-cur_ndev = reduced["n_devices"]
-floor = 0.7 * ref
-print(f"reduced-sweep/serial speedup: current={ratio:.2f}x baseline={ref}x "
-      f"floor={floor:.2f}x (devices: current={cur_ndev} baseline={base_ndev})")
-print(f"(informational) reduced-sweep slots_runs_per_s: "
-      f"current={reduced['slots_runs_per_s']} "
-      f"baseline-host={base['quick_baseline']['batched']['slots_runs_per_s']}")
-
-transfer_x = current["host_transfer"]["reduction_x"]
-print(f"host-transfer reduction (trace vs reduced): {transfer_x}x "
-      f"(gate: >= 10x)")
-fail = False
-if transfer_x < 10:
-    print("FAIL: on-device reduction ships too many bytes to the host")
-    fail = True
-if cur_ndev != base_ndev:
-    print(f"SKIP speedup gate: host exposes {cur_ndev} XLA devices, baseline "
-          f"was recorded on {base_ndev} — the ratio is not comparable; "
-          "regenerate BENCH_sim_engine.json on this host to re-arm the gate")
-elif ratio < floor:
-    print("FAIL: reduced-sweep speedup regressed more than 30% vs "
-          "BENCH_sim_engine.json")
-    fail = True
-sys.exit(1 if fail else 0)
-EOF
-
-  echo
-  echo "=== bench-smoke: large-N cell-list contact backend gate (N=4096) ==="
-  python -m benchmarks.sim_engine --scaling 4096
-  python - <<'EOF'
-import json, sys
-
-with open("reports/bench/sim_scaling.json") as f:
-    rows = json.load(f)["rows"]
-cells = next(r for r in rows if r["backend"] == "cells")
-speedup = cells["speedup_x"]
-overhead = cells.get("zero_fault_overhead_pct")
-print(f"N=4096 cells-over-dense speedup: {speedup}x (gate: >= 2x), "
-      f"nbr_overflow={cells['nbr_overflow']}, "
-      f"zero_fault_overhead_pct={overhead} (gate: < 5%)")
-fail = False
-if speedup is None or speedup < 2.0:
-    print("FAIL: cell-list backend no longer beats the dense sweep at "
-          "N=4096")
-    fail = True
-if cells["nbr_overflow"] != 0:
-    print("FAIL: auto-sized neighbor lists overflowed (contact detection "
-          "undercounted)")
-    fail = True
-if overhead is None or overhead >= 5.0:
-    print("FAIL: the all-zero-rates fault path must trace the identical "
-          "program — measured overhead breaks the <5% budget")
-    fail = True
-sys.exit(1 if fail else 0)
-EOF
-  echo "OK"
 fi

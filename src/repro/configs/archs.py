@@ -51,7 +51,7 @@ def jamba_52b(**kw) -> ArchConfig:
 
 @register_arch("whisper-small")
 def whisper_small(**kw) -> ArchConfig:
-    # Encoder-decoder; mel+conv frontend is a STUB (input_specs provides
+    # Encoder-decoder; mel+conv frontend is a STUB (the caller provides
     # 1500 frame embeddings). GELU MLP as in the original.
     return ArchConfig(
         name="whisper-small", n_layers=12, d_model=768, n_heads=12,
@@ -109,7 +109,7 @@ def mamba2_130m(**kw) -> ArchConfig:
 @register_arch("llama-3.2-vision-11b")
 def llama32_vision(**kw) -> ArchConfig:
     # 8 cross-attention layers interleaved every 5th layer; ViT/projector is
-    # a STUB (input_specs provides 1600 patch embeddings at d_model).
+    # a STUB (the caller provides 1600 patch embeddings at d_model).
     return ArchConfig(
         name="llama-3.2-vision-11b", n_layers=40, d_model=4096, n_heads=32,
         n_kv_heads=8, head_dim=128, d_ff=14336, vocab_size=128256,

@@ -29,7 +29,7 @@ class EncoderConfig:
     """Transformer encoder consuming stub frontend embeddings.
 
     The modality frontend (mel+conv for audio, ViT for vision) is a STUB per
-    the assignment: ``input_specs`` provides (batch, enc_seq, d_model)
+    the assignment: the caller provides (batch, enc_seq, d_model)
     embeddings directly.
     """
 

@@ -1,9 +1,10 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, the benchmarks, the
-sweep-dispatch workers): where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
-reads it itself and nothing here overrides it; otherwise the cache goes to
-``.jax_cache/`` at the root of the checkout. The path is part of what a
+One rule for every entry point (``chip_smoke.py``, ``bench/run.py``,
+``benchmarks/run.py``, the sweep-dispatch workers): where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing here
+overrides it; otherwise the cache goes to ``.jax_cache/`` at the root of
+the checkout. The path is part of what a
 process can find again, so it is fixed — never a temporary directory.
 The cache only saves compile time: nothing the program computes depends
 on what is in it.

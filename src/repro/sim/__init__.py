@@ -45,10 +45,6 @@ Module map
                   quantiles) that cut host transfers >100x.
                   ``simulate_batch`` is a thin wrapper over
                   ``sweep.run(..., reduce="trace")``.
-
-``repro.core.simulator`` remains a thin backward-compatible shim over this
-package (and keeps the legacy monolithic step as the equivalence-test
-reference).
 """
 
 from repro.sim.engine import (

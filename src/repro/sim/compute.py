@@ -26,7 +26,8 @@ word ops —
 * single bit   ``packed_onehot``
 
 — which is exact (no float round trip), so the packed engine stays
-*bitwise* equivalent to the legacy boolean step while shrinking the
+*bitwise* equivalent to a boolean step (the plain reference
+``bench/reference.py``, ``tests/test_sim_engine.py``) while shrinking the
 ``lax.scan`` carry ~8x (XLA stores a bool in one byte; 32 bools per word
 is 4 bytes) and cutting the memory traffic the batched CPU engine is
 bound by.
@@ -226,8 +227,8 @@ def pack_mask(mask: jnp.ndarray) -> jnp.ndarray:
     The merge queue carries an incorporation mask per queued job; packed,
     the queue payload shrinks 32x — it is the largest buffer the scan
     carries, and on CPU the batched engine is memory-traffic-bound. Bit
-    packing is exact, so the engine stays bit-equivalent to the legacy
-    step."""
+    packing is exact, so the engine stays bit-equivalent to the boolean
+    reference."""
     k = mask.shape[-1]
     pad = (-k) % 32
     if pad:

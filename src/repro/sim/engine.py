@@ -8,8 +8,7 @@ into one slot-step function, scans it over time, and exposes
   single jit compilation*: the scenario axis vmaps over stacked dynamic
   ``FGParams`` (T_L, T_T, T_M, t0, lam, tau_l, Λ) and the seed axis vmaps
   over PRNG keys. The paper's figure sweeps become one batched device
-  program instead of a serial per-point loop (``benchmarks/sim_engine.py``
-  measures the speedup).
+  program instead of a serial per-point loop.
 
 The per-slot traced program is independent of the model count ``M`` (the
 legacy Python-over-``M`` enqueue loops are scatter ops in

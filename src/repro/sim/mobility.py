@@ -25,7 +25,7 @@ simulator select matching physics via the same string (see
 
 The two-key step contract exists so the engine can split its slot key the
 same way for every model; ``rdm`` consumes both keys exactly like the
-legacy monolithic simulator, keeping the refactored engine bit-compatible
+plain reference ``bench/reference.py``, keeping the engine bit-compatible
 with it (``tests/test_sim_engine.py``).
 """
 

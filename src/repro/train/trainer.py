@@ -46,7 +46,7 @@ def _batch_axes(mesh: Mesh) -> tuple[str, ...]:
 
 def train_shardings(cfg: ArchConfig, mesh: Mesh, *, mode: str,
                     optimizer: Optimizer, rules: ShardingRules = DEFAULT_RULES):
-    """(abstract state, specs) for the chosen mode — used by dryrun/launch."""
+    """(abstract state, specs) for the chosen mode."""
     abstract, logical = abstract_lm(cfg)
     if mode == "gossip":
         R = 1

@@ -1,8 +1,7 @@
 import os
 import sys
 
-# Tests must see the default single CPU device (the 512-device override is
-# exclusively for launch/dryrun.py). Make sure src/ is importable.
+# Tests see the default single CPU device. Make sure src/ is importable.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # Run the CPU backend without fused multiply-add. XLA's CPU compiler

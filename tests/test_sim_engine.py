@@ -1,8 +1,8 @@
 """Engine-level guarantees of the modular simulator (``repro.sim``):
 
-1. the refactored engine reproduces the legacy monolithic step *bit for
-   bit* (same PRNG schedule, same op order) — the refactor is a pure
-   restructuring;
+1. the engine reproduces the plain reference of the benchmark
+   (``bench/reference.py``: boolean masks, no kernels) *bit for bit* at
+   the sample points (same PRNG schedule, same arithmetic);
 2. ``simulate_batch`` agrees with the single-run path pointwise, so a
    batched sweep is a drop-in replacement for a serial loop;
 3. the non-RDM mobility models drive the full protocol end to end.
@@ -12,52 +12,43 @@ import jax
 import numpy as np
 import pytest
 
+from bench import reference
 from repro.configs.fg_paper import paper_params
-from repro.core.simulator import _legacy_run
 from repro.sim import SimConfig, simulate, simulate_batch
 from repro.sim.engine import _run_single, dynamic_params
 
 CFG = SimConfig(n_nodes=60, n_slots=300, sample_every=4)
+TRACES = ("availability", "busy_frac", "stored", "obs_birth",
+          "obs_holders", "model_holders", "n_in_rz")
 
 
-def test_engine_matches_legacy_step_bitwise():
-    p = paper_params(lam=0.2, M=3, Lam=2)
-    key = jax.random.PRNGKey(7)
-    legacy = _legacy_run(
-        key, CFG,
-        dict(t0=p.t0, T_L=p.T_L, T_T=p.T_T, T_M=p.T_M, lam=p.lam, tau_l=p.tau_l),
-        int(p.M), int(p.Lam),
-    )
-    new = _run_single(key, dynamic_params(p), CFG, int(p.M))
-    # legacy emits every slot; the engine emits at the sample points
-    # (slot s-1, 2s-1, ...) — the values there must agree bit for bit
-    sl = slice(CFG.sample_every - 1, None, CFG.sample_every)
-    for k in ("availability", "busy_frac", "stored", "obs_birth",
-              "obs_holders", "model_holders", "n_in_rz"):
-        np.testing.assert_array_equal(
-            np.asarray(legacy[k])[sl], np.asarray(new[k]), err_msg=k
-        )
-
-
-def test_packed_engine_matches_legacy_with_pad_bits():
-    """Same bitwise pin with K not a multiple of 32 (live pad bits in the
-    last mask word) and several models — the packed word algebra must not
-    leak into or read from the pad region."""
-    cfg = SimConfig(n_nodes=40, n_slots=240, sample_every=4, k_obs=40)
-    p = paper_params(lam=0.3, M=2, Lam=2)
-    key = jax.random.PRNGKey(11)
-    legacy = _legacy_run(
-        key, cfg,
-        dict(t0=p.t0, T_L=p.T_L, T_T=p.T_T, T_M=p.T_M, lam=p.lam, tau_l=p.tau_l),
-        int(p.M), int(p.Lam),
-    )
+@pytest.mark.parametrize("cfg, p, seed", [
+    (CFG, paper_params(lam=0.2, M=3, Lam=2), 7),
+    # K not a multiple of 32 (live pad bits in the last mask word) and
+    # several models: the packed word algebra must not leak into or read
+    # from the pad region
+    (SimConfig(n_nodes=40, n_slots=240, sample_every=4, k_obs=40),
+     paper_params(lam=0.3, M=2, Lam=2), 11),
+], ids=["three_models", "pad_bits"])
+def test_engine_matches_the_reference_bitwise(cfg, p, seed):
+    shape = reference.Shape(
+        n_nodes=cfg.n_nodes, area_side=cfg.area_side,
+        rz_radius=cfg.rz_radius, r_tx=cfg.r_tx, speed=cfg.speed,
+        dir_change_rate=cfg.dir_change_rate, dt=cfg.dt,
+        n_slots=cfg.n_slots, k_obs=cfg.k_obs, q_train=cfg.q_train,
+        q_merge=cfg.q_merge, M=int(p.M), Lam=int(p.Lam))
+    p_ref = {k: np.float32(getattr(p, k))[None]
+             for k in ("t0", "T_T", "T_M", "tau_l", "lam")}
+    p_ref["T_L"] = np.float32(2.0 * p.L / p.C)[None]
+    key = jax.random.PRNGKey(seed)
+    want = reference.run_many(key[None], p_ref, shape)
     new = _run_single(key, dynamic_params(p), cfg, int(p.M))
-    sl = slice(cfg.sample_every - 1, None, cfg.sample_every)
-    for k in ("availability", "busy_frac", "stored", "obs_birth",
-              "obs_holders", "model_holders", "n_in_rz"):
+    # the reference emits every slot; the engine emits at the sample
+    # points (slot s-1, 2s-1, ...): the values there must agree bit for bit
+    pts = reference.sample_points(cfg.n_slots, cfg.sample_every)
+    for k in TRACES:
         np.testing.assert_array_equal(
-            np.asarray(legacy[k])[sl], np.asarray(new[k]), err_msg=k
-        )
+            np.asarray(want[k][0])[pts], np.asarray(new[k]), err_msg=k)
 
 
 def test_batch_matches_single_runs():
